@@ -45,7 +45,6 @@ import numpy as np
 
 from repro.core import inumerics as inum
 from repro.kernels import ops
-from repro.kernels.common import set_interpret
 
 SEED = 0
 
@@ -86,11 +85,9 @@ def run(backend: str = "jnp", smoke: bool = False,
     """``strict=False`` (the smoke default) skips families whose backend is
     unavailable instead of failing the whole bench."""
     assert backend in ("jnp", "pallas"), backend
-    from repro.kernels.common import interpret_mode
-
-    prev_backend, prev_interpret = ops.backend(), interpret_mode()
-    ops.set_backend(backend)
-    set_interpret(True)  # pallas backend on CPU = interpret-mode correctness
+    # interpret mode follows the platform: the pallas backend runs
+    # interpreted on CPU (correctness timing) and compiled on a TPU
+    prev_backend = ops.set_backend(backend)
     # interpret mode is slow: shrink the sweep so --backend pallas stays
     # usable as a correctness-timing smoke rather than a coffee break
     small = smoke or backend == "pallas"
@@ -101,7 +98,6 @@ def run(backend: str = "jnp", smoke: bool = False,
         return _run_rows(small, reps, backend, strict)
     finally:
         ops.set_backend(prev_backend)
-        set_interpret(prev_interpret)
 
 
 def _run_rows(small: bool, reps: int, backend: str,
@@ -442,7 +438,7 @@ def _paged_family(reps, backend):
 def _sweep_timer(fn):
     """One timed call after a warm call — interpret-mode Pallas is slow
     enough that relative candidate ordering is stable at a single rep; on
-    a real TPU (set_interpret(False)) raise reps in the loop below."""
+    a TPU, where the kernels run compiled, raise reps in the loop below."""
     def timer(blocks):
         f = lambda: fn(*blocks)
         jax.block_until_ready(f())
@@ -465,10 +461,11 @@ def sweep(backend: str = "pallas", families: tuple[str, ...] = (),
     ``autotune.cache_path()`` (``REPRO_AUTOTUNE_CACHE`` overridable), the
     JSON every ``ops.py`` entry point consults before the cost table.
 
-    On a real TPU run with ``set_interpret(False)`` first (deployments do)
-    and the numbers are hardware truth; on CPU the kernel families run
-    interpret-mode Pallas — functionally exact, useful for exercising the
-    loop, not for real tile choices.  Returns the recorded keys.
+    On a TPU the kernels run compiled and the numbers are hardware truth;
+    on CPU the kernel families run interpret-mode Pallas — functionally
+    exact, useful for exercising the loop, not for real tile choices.
+    Entries are keyed by device kind, so a CPU sweep never chooses a
+    chip's tiles.  Returns the recorded keys.
     """
     from repro.kernels import autotune
     from repro.kernels.flash_attention import flash_attention
@@ -563,7 +560,7 @@ def sweep(backend: str = "pallas", families: tuple[str, ...] = (),
     g = hq // hkv
     entries.append((
         f"decode/{sd}x{d}x{g}",
-        [(bk,) for bk in _divisor_tiles(sd, cap=2048)],
+        [(bk,) for bk in autotune.decode_tiles(sd)],
         _sweep_timer(lambda bk: int8_kv_decode_attention(
             qd, kq, ksc, kq, ksc, dpos, dqpos, bk=bk))))
 
@@ -680,15 +677,14 @@ def main() -> None:
     ap.add_argument("--families", default="",
                     help="comma list to restrict --sweep (e.g. gemm,attn)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.sweep:
         from repro.kernels import ops as _ops
         from repro.kernels.common import interpret_mode
-        # NOTE: interpret mode is left AS-IS (CPU default: True) — a real
-        # TPU deployment calls set_interpret(False) at startup and the
-        # sweep must time actual hardware kernels, not force emulation
-        # timings into the production measured cache
-        prev_b = _ops.backend()
-        _ops.set_backend(args.backend)
+        # interpret mode follows the platform: compiled on a TPU, so the
+        # sweep times hardware kernels there; interpreted on CPU
+        prev_b = _ops.set_backend(args.backend)
         try:
             fams = tuple(f for f in args.families.split(",") if f)
             print(f"sweep: backend={args.backend} "

@@ -251,6 +251,8 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import cgra_tables, e2e_bench, kernel_bench, tp_bench
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     # smoke implies non-strict (kernel_bench's default): unavailable kernel
     # families are skipped, not fatal

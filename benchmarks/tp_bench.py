@@ -40,8 +40,10 @@ _MARK = "TPBENCH_ROWS:"
 
 
 def run(smoke: bool = False) -> list[tuple]:
-    """Spawn the emulated-mesh worker and collect its rows."""
-    env = dict(os.environ)
+    """Spawn the emulated-mesh worker and collect its rows.  The worker is
+    pinned to the CPU platform: on a chip host the parent may hold the
+    TPU, and a child that opened it would fail or hang."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8").strip()
     env["PYTHONPATH"] = os.pathsep.join(
@@ -147,6 +149,8 @@ if __name__ == "__main__":
             + " --xla_force_host_platform_device_count=8").strip()
         sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
                                         "src"))
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
         _worker("--smoke" in sys.argv)
     else:
         for r in run(smoke=True):

@@ -2,7 +2,8 @@
 
 1. Run a Table-II kernel (gemm) through the NX-CGRA model: static schedule,
    cycle/energy simulation, published-style metrics.
-2. Run the same integer arithmetic as a Pallas TPU kernel (interpret mode)
+2. Run the same integer arithmetic as a Pallas TPU kernel (interpreted on
+   CPU, compiled on a TPU)
    and check bit-exactness.
 3. Run a W8A8 transformer forward pass — the technique at model scale.
 
@@ -31,10 +32,9 @@ print(f"[CGRA] gemm: {res.cycles} cycles, {m.mops:.0f} MOPS, "
 # --- 2. the TPU kernel, same arithmetic --------------------------------------
 from repro.core import inumerics as inum
 from repro.kernels import ops, ref
-from repro.kernels.common import set_interpret
 
-ops.set_backend("pallas")
-set_interpret(True)
+# the Pallas kernel: interpreted on CPU, compiled on a TPU
+prev_backend = ops.set_backend("pallas")
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.integers(-127, 128, (64, 128)), jnp.int8)
 w = jnp.asarray(rng.integers(-127, 128, (128, 96)), jnp.int8)
@@ -42,7 +42,7 @@ rq = inum.compute_requant_params(1e-3, 128 * 127 * 127)
 exact = bool((ops.gemm_i8(x, w, requant=rq)
               == ref.int8_gemm_ref(x, w, requant=rq)).all())
 print(f"[Pallas] int8 GEMM + requant epilogue bit-exact vs oracle: {exact}")
-ops.set_backend("jnp")
+ops.set_backend(prev_backend)
 
 # --- 3. W8A8 transformer -----------------------------------------------------
 from repro.configs import get_config

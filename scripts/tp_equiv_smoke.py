@@ -1,8 +1,9 @@
 """CI smoke: serving tensor parallel must be a LAYOUT change only.
 
-Spins up 8 emulated CPU devices (the XLA host-platform flag below must be
-set before jax initializes) and drains identical workloads through the
-packed engine at tp=1 (the plain jit), tp=8 barrier, and tp=8 overlap,
+Spins up 8 emulated CPU devices (the platform pin and the XLA
+host-platform flag below must be set before jax initializes) and drains
+identical workloads through the packed engine at tp=1 (the plain jit),
+tp=8 barrier, and tp=8 overlap,
 asserting bit-identical token streams across all three.  Settings cover
 the matrix the sharded step must survive: greedy and sampled, dense and
 paged KV, spec_k in {0, 4}, and a tiny-pool run where preemption + KV
@@ -16,6 +17,7 @@ import itertools
 import os
 import sys
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "")
     + " --xla_force_host_platform_device_count=8").strip()

@@ -29,7 +29,6 @@ import jax
 
 from repro.configs import get_config
 from repro.kernels import ops, ref
-from repro.kernels.common import set_interpret
 from repro.models import init_params
 from repro.quant import ptq_quantize_params
 from repro.quant.ptq import DEFAULT_W4_POLICY
@@ -86,9 +85,7 @@ def drain(params, cfg) -> dict:
 
 
 def main() -> None:
-    set_interpret(True)
-    prev = ops.backend()
-    ops.set_backend("pallas")
+    prev = ops.set_backend("pallas")
     try:
         for arch in ("starcoder2-3b", "codeqwen1.5-7b"):
             cfg = get_config(arch, precision="w4a8", reduced=True)

@@ -74,13 +74,3 @@ def pipeline_apply(layer_fn, stage_params, xs, axis_name: str):
     # only the last stage accumulated anything: psum replicates it everywhere
     return jax.lax.psum(outputs, axis_name)
 
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (new API first, 0.4.x fallback)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)

@@ -5,16 +5,23 @@ block sizes instead of hardcoding MXU-shaped constants.  Resolution order
 for a (kernel, shape, dtype, backend) key:
 
   1. **Measured cache** — a JSON file of block sizes that were actually
-     timed on this machine (``REPRO_AUTOTUNE_CACHE`` env var, default
-     ``.autotune/measured.json`` at the repo root).  Benchmarks populate it
-     via ``measure``; an exact key hit always wins.
+     timed (``REPRO_AUTOTUNE_CACHE`` env var, default
+     ``.autotune/measured.json`` at the repo root, not committed).
+     Benchmarks populate it via ``measure``; every stored key names the
+     ``device_kind`` it was timed on, and an exact hit for THIS device's
+     kind always wins — a CPU sweep left in a working tree never chooses
+     a chip's tiles.
   2. **Cost-model-seeded table** — the analytic tile costs in
      ``core.costmodel`` (padding waste, compute/HBM roofline, grid-step
      overhead, VMEM wall) evaluated over the legal candidate lattice; the
      argmin is memoized per process.
   3. The candidate lattice itself guarantees legality, so there is no
      third fallback: every returned tile is MXU/VPU-legal (lane dims are
-     multiples of 128, sublane dims multiples of 8) and VMEM-feasible.
+     multiples of 128, sublane dims multiples of 8, or the full extent of
+     the array dim, e.g. a cache length below 128) and VMEM-feasible.
+     Derived operand blocks follow the same rule: the W4A8 kernels lay
+     their group multipliers out so that one K block's groups span a full
+     array dim (``int8_gemm._qmul_blocks``).
 
 The policy is "table, then measure": the cost model gives a good default
 with zero warmup; real deployments run the benchmark sweep once per
@@ -27,6 +34,8 @@ from __future__ import annotations
 import functools
 import json
 import os
+
+import jax
 
 from ..core import costmodel
 
@@ -57,9 +66,26 @@ def _measured() -> dict:
     return _MEASURED
 
 
+def measured_key(key: str) -> str:
+    """The stored form of a lookup key: tagged with the device kind it is
+    timed on (``jax.devices()[0].device_kind``, read at first use)."""
+    return f"{key}@{jax.devices()[0].device_kind}"
+
+
+def tile_source() -> str:
+    """Where this device's tiles come from: the measured cache when it holds
+    entries for this device kind, else the committed cost-model table."""
+    kind = jax.devices()[0].device_kind
+    n = sum(k.endswith("@" + kind) for k in _measured())
+    if n:
+        return f"measured cache {cache_path()} ({n} entries for {kind})"
+    return f"committed cost-model table (no measured entries for {kind})"
+
+
 def record(key: str, blocks: tuple[int, ...], us: float) -> None:
     """Persist a measured (key -> blocks) entry; keeps the fastest."""
     cache = _measured()
+    key = measured_key(key)
     prev = cache.get(key)
     if prev is not None and prev.get("us", float("inf")) <= us:
         return
@@ -108,7 +134,7 @@ def is_mxu_legal(bm: int, bn: int, bk: int) -> bool:
 
 
 def _hit(key: str):
-    ent = _measured().get(key)
+    ent = _measured().get(measured_key(key))
     return tuple(ent["blocks"]) if ent else None
 
 
@@ -409,14 +435,22 @@ def tp_serving_overlap(rows: int, d_model: int, d_ff: int, heads_dim: int,
     return "overlap" if total(True) < total(False) else "barrier"
 
 
+def decode_tiles(s: int) -> list[int]:
+    """Legal KV blocks for the int8-KV decode kernel: divisors of the cache
+    length S that are also legal lane dims of the slot-position row block
+    (multiples of 128, or S itself)."""
+    return [t for t in _divisor_tiles(s, cap=2048)
+            if t % LANE == 0 or t == s] or [s]
+
+
 @functools.lru_cache(maxsize=4096)
 def decode_blocks(s: int, d: int, g: int) -> int:
     """KV block for the int8-KV decode kernel: one query tile (G, D) stays
-    resident; bk divides the cache length S."""
+    resident; bk is drawn from ``decode_tiles(S)``."""
     hit = _hit(f"decode/{s}x{d}x{g}")
     if hit:
         return hit[0]
-    tiles = _divisor_tiles(s, cap=2048)
+    tiles = decode_tiles(s)
     best, best_cost = tiles[0], float("inf")
     for bk in tiles:
         c = costmodel.attention_tile_cost(g, s, d, max(g, 1), bk, in_bytes=1)

@@ -4,6 +4,11 @@ All kernels target TPU (MXU 128x128 systolic matmul, VPU 8x128 lanes,
 ~16 MiB VMEM per core) and are validated on CPU with ``interpret=True``.
 Block shapes default to MXU-aligned multiples of 128; wrappers pad
 arbitrary shapes up to block multiples and slice the result back.
+
+Interpret mode is resolved from the platform at first use, never at
+import: compiled on a TPU backend, interpreted anywhere else.  Asking for
+interpret mode while a TPU is the backend is an error — the chip never
+runs a kernel through the interpreter.
 """
 from __future__ import annotations
 
@@ -15,15 +20,39 @@ LANE = 128          # last-dim tile (VREG lane width, MXU edge)
 SUBLANE = 8         # second-to-last-dim tile for fp32
 MXU = 128
 
-_INTERPRET = [True]  # flipped to False on real TPU deployments
+_INTERPRET: list[bool | None] = [None]  # None: follow the platform
 
 
-def set_interpret(mode: bool) -> None:
-    _INTERPRET[0] = bool(mode)
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
-def interpret_mode() -> bool:
-    return _INTERPRET[0]
+def _refuse_interpret_on_tpu() -> None:
+    if on_tpu():
+        raise RuntimeError(
+            "Pallas interpret mode was requested while a TPU is the JAX "
+            "backend; kernels run compiled on the chip")
+
+
+def set_interpret(mode: bool | None) -> bool | None:
+    """Test hook: force interpret mode on or off (``None`` restores the
+    platform default).  Returns the previous setting for restoring."""
+    if mode:
+        _refuse_interpret_on_tpu()
+    prev, _INTERPRET[0] = _INTERPRET[0], mode
+    return prev
+
+
+def interpret_mode(interpret: bool | None = None) -> bool:
+    """A kernel's interpret flag: the explicit ``interpret`` argument, else
+    the process setting, else the platform (compiled on TPU only)."""
+    if interpret is None:
+        interpret = _INTERPRET[0]
+    if interpret is None:
+        interpret = not on_tpu()
+    if interpret:
+        _refuse_interpret_on_tpu()
+    return bool(interpret)
 
 
 def pad_to(x: jax.Array, multiples: tuple[int, ...], value=0) -> jax.Array:

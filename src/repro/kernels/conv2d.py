@@ -76,5 +76,5 @@ def int8_conv2d(
         out_specs=pl.BlockSpec((1, oh, ow, o), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(
             (n, oh, ow, o), jnp.int8 if requant else I32),
-        interpret=interpret_mode() if interpret is None else interpret,
+        interpret=interpret_mode(interpret),
     )(x, w, bias)

@@ -188,7 +188,7 @@ def int8_flash_attention(
     v3 = v.reshape(b * h, skv, d)
     vs3 = None if v_scale is None else v_scale.reshape(b * h, skv, 1)
     nq, nk = s // bq, skv // bk
-    itp = interpret_mode() if interpret is None else interpret
+    itp = interpret_mode(interpret)
     common = dict(scale=scale, causal=causal, bq=bq, bk=bk, n_kv=nk,
                   rshift=rshift)
 

@@ -203,7 +203,7 @@ def int8_gemm(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), I32)],
-        interpret=interpret_mode() if interpret is None else interpret,
+        interpret=interpret_mode(interpret),
     )(*operands)
 
 
@@ -347,7 +347,7 @@ def dual_gemm_gated(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype),
                         pltpu.VMEM((bm, bn), acc_dtype)],
-        interpret=interpret_mode() if interpret is None else interpret,
+        interpret=interpret_mode(interpret),
     )(*operands)
 
 
@@ -370,11 +370,21 @@ def _unpack_block(packed, bk):
 
     Same nibble layout as ``quantize.pack_int4``: low nibble = even K row,
     high nibble = odd K row.  Three VPU ops (two shifts sign-extend the low
-    nibble, one the high) plus an interleave.
+    nibble, one the high) plus an interleave.  The shifts run on int32
+    lanes: the v5e VPU has no 8-bit shift.
     """
-    lo = jnp.right_shift(jnp.left_shift(packed, 4), 4)
-    hi = jnp.right_shift(packed, 4)
-    return jnp.stack([lo, hi], axis=1).reshape(bk, packed.shape[-1])
+    p = packed.astype(I32)
+    lo = jnp.right_shift(jnp.left_shift(p, 28), 28)
+    hi = jnp.right_shift(p, 4)
+    w = jnp.stack([lo, hi], axis=1).reshape(bk, packed.shape[-1])
+    return w.astype(jnp.int8)
+
+
+def _qmul_blocks(qmul, n_k: int):
+    """(K/group, N) group multipliers -> (K/bk, bk/group, N): one K block's
+    groups form a full-extent second-minor block, so a block of only a few
+    group rows stays legal under the TPU's (8, 128) tiling rule."""
+    return qmul.reshape(n_k, qmul.shape[0] // n_k, qmul.shape[1])
 
 
 def _w4a8_kernel(*refs, n_k: int, epilogue: str, gelu_scale: float,
@@ -394,7 +404,7 @@ def _w4a8_kernel(*refs, n_k: int, epilogue: str, gelu_scale: float,
 
     x = x_ref[...]
     w = _unpack_block(w_ref[...], bk)
-    qm = qm_ref[...].astype(I32)  # (bk // group, bn) int8 group multipliers
+    qm = qm_ref[0].astype(I32)  # (bk // group, bn) int8 group multipliers
     # one MXU contraction per scale group; the int8 group multiplier folds
     # in WITHOUT leaving the int32 accumulator, so the combine is exact and
     # order-independent — bit-identical to the unfused unpack -> int8-GEMM
@@ -475,11 +485,12 @@ def int4_gemm(
 
     n_k = k // bk
     grid = (m // bm, n // bn, n_k)
-    operands = [x, w4, qmul, w_scale.reshape(1, n), x_scale]
+    operands = [x, w4, _qmul_blocks(qmul, n_k), w_scale.reshape(1, n),
+                x_scale]
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
         pl.BlockSpec((bk // 2, bn), lambda i, j, kk: (kk, j)),
-        pl.BlockSpec((bk // group, bn), lambda i, j, kk: (kk, j)),
+        pl.BlockSpec((1, bk // group, bn), lambda i, j, kk: (kk, 0, j)),
         pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         pl.BlockSpec((bm, 1), lambda i, j, kk: (i, 0)),
     ]
@@ -503,7 +514,7 @@ def int4_gemm(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), I32)],
-        interpret=interpret_mode() if interpret is None else interpret,
+        interpret=interpret_mode(interpret),
     )(*operands)
 
 
@@ -526,7 +537,7 @@ def _dual_w4a8_kernel(*refs, n_k: int, act: str, act_scale: float,
     x = x_ref[...]
     wu = _unpack_block(wu_ref[...], bk)
     wg = _unpack_block(wg_ref[...], bk)
-    um, gm = um_ref[...].astype(I32), gm_ref[...].astype(I32)
+    um, gm = um_ref[0].astype(I32), gm_ref[0].astype(I32)
     for gi in range(bk // group):
         xg = x[:, gi * group:(gi + 1) * group]
         pu = jax.lax.dot_general(
@@ -604,14 +615,15 @@ def dual_int4_gemm_gated(
 
     n_k = k // bk
     grid = (m // bm, n // bn, n_k)
-    operands = [x, up4, gate4, up_mul, gate_mul,
+    operands = [x, up4, gate4, _qmul_blocks(up_mul, n_k),
+                _qmul_blocks(gate_mul, n_k),
                 up_scale.reshape(1, n), gate_scale.reshape(1, n), x_scale]
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
         pl.BlockSpec((bk // 2, bn), lambda i, j, kk: (kk, j)),
         pl.BlockSpec((bk // 2, bn), lambda i, j, kk: (kk, j)),
-        pl.BlockSpec((bk // group, bn), lambda i, j, kk: (kk, j)),
-        pl.BlockSpec((bk // group, bn), lambda i, j, kk: (kk, j)),
+        pl.BlockSpec((1, bk // group, bn), lambda i, j, kk: (kk, 0, j)),
+        pl.BlockSpec((1, bk // group, bn), lambda i, j, kk: (kk, 0, j)),
         pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         pl.BlockSpec((bm, 1), lambda i, j, kk: (i, 0)),
@@ -628,5 +640,5 @@ def dual_int4_gemm_gated(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), I32),
                         pltpu.VMEM((bm, bn), I32)],
-        interpret=interpret_mode() if interpret is None else interpret,
+        interpret=interpret_mode(interpret),
     )(*operands)

@@ -11,7 +11,9 @@ int8 mode): ring-buffer `pos_ids` masking (empty slots, causal bound,
 sliding window) and GQA via a q-register blocked over query-head groups.
 
 Grid: (B * Hkv, S/bk); the query block (G, D) stays resident; each step
-loads (bk, D) int8 K and V tiles + (bk, 1) scales.
+loads (bk, D) int8 K and V tiles + (bk, 1) scales + a (1, bk) row of slot
+positions.  The per-sequence query positions ride the scalar-prefetch path
+(SMEM), so no block is narrower than the TPU's (8, 128) tiling allows.
 """
 from __future__ import annotations
 
@@ -29,9 +31,10 @@ F32 = jnp.float32
 NEG = -1e30
 
 
-def _kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref, qpos_ref, o_ref,
-            m_scr, l_scr, acc_scr, *, scale: float, window: int, n_kv: int):
-    j = pl.program_id(1)
+def _kernel(qpos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref, o_ref,
+            m_scr, l_scr, acc_scr, *, scale: float, window: int, n_kv: int,
+            hkv: int):
+    i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -42,15 +45,15 @@ def _kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref, qpos_ref, o_ref,
     q = q_ref[0].astype(F32)                     # (G, D)
     k = k_ref[0].astype(F32) * ks_ref[0]         # (bk, D) dequant in-register
     v = v_ref[0].astype(F32) * vs_ref[0]
-    kpos = pos_ref[0]                            # (bk,) absolute positions
-    qpos = qpos_ref[0]                           # (1,) this sequence's step
+    kpos = pos_ref[0]                            # (1, bk) absolute positions
+    qpos = qpos_ref[i // hkv]                    # this sequence's step (SMEM)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=F32) * scale  # (G, bk)
     valid = (kpos >= 0) & (kpos <= qpos)
     if window:
         valid &= kpos > (qpos - window)
-    s = jnp.where(valid[None, :], s, NEG)
+    s = jnp.where(valid, s, NEG)
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -94,29 +97,32 @@ def int8_kv_decode_attention(
     ks = jnp.transpose(k_s, (0, 2, 1, 3)).reshape(b * hkv, s, 1)
     vq = jnp.transpose(v_q, (0, 2, 1, 3)).reshape(b * hkv, s, d)
     vs = jnp.transpose(v_s, (0, 2, 1, 3)).reshape(b * hkv, s, 1)
-    pos = jnp.repeat(pos_ids, hkv, axis=0)                 # (B*Hkv, S)
-    qp = jnp.repeat(qpos.reshape(b, 1), hkv, axis=0)       # (B*Hkv, 1)
+    # (B, 1, S): a (1, bk) row block spans the full second-minor extent
+    pos = pos_ids.reshape(b, 1, s)
     n_kv = s // bk
-    kernel = functools.partial(_kernel, scale=scale, window=window, n_kv=n_kv)
+    kernel = functools.partial(_kernel, scale=scale, window=window, n_kv=n_kv,
+                               hkv=hkv)
     o = pl.pallas_call(
         kernel,
-        grid=(b * hkv, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, g, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, bk, 1), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, bk, 1), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, bk), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, g, d), lambda i, j: (i, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * hkv, n_kv),
+            in_specs=[
+                pl.BlockSpec((1, g, d), lambda i, j, qp: (i, 0, 0)),
+                pl.BlockSpec((1, bk, d), lambda i, j, qp: (i, j, 0)),
+                pl.BlockSpec((1, bk, 1), lambda i, j, qp: (i, j, 0)),
+                pl.BlockSpec((1, bk, d), lambda i, j, qp: (i, j, 0)),
+                pl.BlockSpec((1, bk, 1), lambda i, j, qp: (i, j, 0)),
+                pl.BlockSpec((1, 1, bk), lambda i, j, qp: (i // hkv, 0, j)),
+            ],
+            out_specs=pl.BlockSpec((1, g, d), lambda i, j, qp: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((g, 1), F32),
+                pltpu.VMEM((g, 1), F32),
+                pltpu.VMEM((g, d), F32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((b * hkv, g, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), F32),
-            pltpu.VMEM((g, 1), F32),
-            pltpu.VMEM((g, d), F32),
-        ],
-        interpret=interpret_mode() if interpret is None else interpret,
-    )(q4, kq, ks, vq, vs, pos, qp)
+        interpret=interpret_mode(interpret),
+    )(qpos.astype(jnp.int32), q4, kq, ks, vq, vs, pos)
     return o.reshape(b, hq, d)

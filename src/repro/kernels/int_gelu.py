@@ -79,6 +79,6 @@ def int_gelu(
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int8),
-        interpret=interpret_mode() if interpret is None else interpret,
+        interpret=interpret_mode(interpret),
     )(x2.astype(I32))
     return out.reshape(orig_shape)

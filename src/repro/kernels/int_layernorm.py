@@ -83,6 +83,6 @@ def int_layernorm(
         ],
         out_specs=pl.BlockSpec((bm, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, d), I32),
-        interpret=interpret_mode() if interpret is None else interpret,
+        interpret=interpret_mode(interpret),
     )(x2.astype(I32), gamma_q.astype(I32), beta_q.astype(I32))
     return out.reshape(orig_shape)

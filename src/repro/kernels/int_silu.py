@@ -65,6 +65,6 @@ def int_silu(
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), I32),
-        interpret=interpret_mode() if interpret is None else interpret,
+        interpret=interpret_mode(interpret),
     )(x2.astype(I32))
     return out.reshape(orig_shape)

@@ -84,6 +84,6 @@ def int_softmax(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int8),
-        interpret=interpret_mode() if interpret is None else interpret,
+        interpret=interpret_mode(interpret),
     )(*operands)
     return out.reshape(orig_shape)

@@ -1,13 +1,17 @@
 """Public jit'd entry points for the kernels package.
 
 Call sites (models, serving engine) go through these wrappers, which handle
-arbitrary shapes (padding to block multiples), pick block sizes per
+arbitrary shapes (padding to block multiples) and pick block sizes per
 (shape, dtype, backend) via ``kernels.autotune`` (cost-model-seeded table
-with an optional measured cache — no hardcoded tiles), and fall back to the
-pure-jnp reference implementation when Pallas is unavailable (e.g. the
-512-device dry-run on the CPU backend, where interpret-mode execution would
-be prohibitive).  ``set_backend("pallas"|"jnp")`` flips the default;
-real-TPU deployments use "pallas".
+with an optional measured cache — no hardcoded tiles).
+
+The kernel backend is resolved from the platform at first use, never at
+import: "pallas" (compiled kernels) on a TPU, "jnp" (the XLA reference
+path in ``ref.py``) anywhere else — e.g. CPU tests and the 512-device
+dry-run, where interpret-mode execution would be prohibitive.
+``set_backend`` is the hook tests and reference comparisons use; the
+serving engine refuses to trace its step on a TPU with the jnp path
+selected (``check_serving_backend``).
 
 Fused epilogue entry points (``gemm_i8_gelu``, ``gemm_i8_add``,
 ``gemm_w8a8``, the dual-GEMM ``gated_mlp``/``gated_mlp_w8a8``, and their
@@ -24,7 +28,7 @@ import jax.numpy as jnp
 
 from ..core.inumerics import RequantParams
 from . import autotune, ref
-from .common import pad_to
+from .common import on_tpu, pad_to
 from .conv2d import int8_conv2d
 from .flash_attention import flash_attention
 from .int8_flash_attention import int8_flash_attention
@@ -36,20 +40,35 @@ from .int_layernorm import int_layernorm
 from .int_softmax import int_softmax
 from .quantize import quantize_rows, requantize_i32
 
-_BACKEND = ["jnp"]  # "pallas" on TPU; "jnp" (XLA reference path) elsewhere
+_BACKEND: list[str | None] = [None]  # None: follow the platform
 
 
-def set_backend(name: str) -> None:
-    assert name in ("pallas", "jnp"), name
-    _BACKEND[0] = name
+def set_backend(name: str | None) -> str | None:
+    """Test hook: force "pallas" or "jnp" (``None`` restores the platform
+    default).  Returns the previous setting for restoring."""
+    if name not in ("pallas", "jnp", None):
+        raise ValueError(f"unknown kernel backend {name!r}")
+    prev, _BACKEND[0] = _BACKEND[0], name
+    return prev
 
 
 def backend() -> str:
+    if _BACKEND[0] is None:
+        return "pallas" if on_tpu() else "jnp"
     return _BACKEND[0]
 
 
 def _use_pallas() -> bool:
-    return _BACKEND[0] == "pallas"
+    return backend() == "pallas"
+
+
+def check_serving_backend() -> None:
+    """On a TPU the serving path runs the compiled Pallas kernels; tracing
+    it with the jnp reference path selected is an error, not a fallback."""
+    if on_tpu() and backend() != "pallas":
+        raise RuntimeError(
+            "the jnp reference kernel path is selected while a TPU is the "
+            "JAX backend; serving on the chip runs the Pallas kernels")
 
 
 # ---------------------------------------------------------------------------

@@ -18,8 +18,10 @@ name the null page (id 0, ``ppos`` forever -1), so the ordinary position
 mask — the same one the dense decode kernel applies — hides them.
 
 Grid: (B * Hkv, MP); the query block (G, D) stays resident, each step
-gathers one (ps, D) K and V page tile + their (ps, 1) scale vectors.
-int8 pages carry f32 scales; bf16 pages skip the scale streams.
+gathers one (ps, D) K and V page tile + their (ps, 1) scale vectors and
+the page's (1, ps) slot positions.  int8 pages carry f32 scales; bf16
+pages skip the scale streams.  The lane query positions are a second
+scalar-prefetch operand (SMEM) next to the page table.
 """
 from __future__ import annotations
 
@@ -37,15 +39,15 @@ F32 = jnp.float32
 NEG = -1e30
 
 
-def _kernel(pt_ref, q_ref, *refs, scale: float, window: int, n_pages_grid: int,
-            int8: bool):
+def _kernel(pt_ref, qpos_ref, q_ref, *refs, scale: float, window: int,
+            n_pages_grid: int, int8: bool, hkv: int):
     if int8:
-        k_ref, ks_ref, v_ref, vs_ref, pos_ref, qpos_ref, o_ref = refs[:7]
-        m_scr, l_scr, acc_scr = refs[7:]
+        k_ref, ks_ref, v_ref, vs_ref, pos_ref, o_ref = refs[:6]
+        m_scr, l_scr, acc_scr = refs[6:]
     else:
-        k_ref, v_ref, pos_ref, qpos_ref, o_ref = refs[:5]
-        m_scr, l_scr, acc_scr = refs[5:]
-    j = pl.program_id(1)
+        k_ref, v_ref, pos_ref, o_ref = refs[:4]
+        m_scr, l_scr, acc_scr = refs[4:]
+    i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -60,15 +62,15 @@ def _kernel(pt_ref, q_ref, *refs, scale: float, window: int, n_pages_grid: int,
     else:
         k = k_ref[0, 0].astype(F32)
         v = v_ref[0, 0].astype(F32)
-    kpos = pos_ref[0]                              # (ps,) absolute positions
-    qpos = qpos_ref[0]                             # (1,) this lane's step
+    kpos = pos_ref[0]                              # (1, ps) absolute positions
+    qpos = qpos_ref[i // hkv]                      # this lane's step (SMEM)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=F32) * scale  # (G, ps)
     valid = (kpos >= 0) & (kpos <= qpos)
     if window:
         valid &= kpos > (qpos - window)
-    s = jnp.where(valid[None, :], s, NEG)
+    s = jnp.where(valid, s, NEG)
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -116,11 +118,11 @@ def paged_decode_attention(
     q4 = q.reshape(b, hkv, g, d).reshape(b * hkv, g, d)
     kq = jnp.transpose(pk, (0, 2, 1, 3))
     vq = jnp.transpose(pv, (0, 2, 1, 3))
-    qp = jnp.repeat(qpos.reshape(b, 1), hkv, axis=0)       # (B*Hkv, 1)
 
-    page_idx = lambda i, j, pt_ref: (pt_ref[i // hkv, j], i % hkv, 0, 0)
+    page_idx = lambda i, j, pt_ref, qp_ref: (pt_ref[i // hkv, j], i % hkv,
+                                             0, 0)
     in_specs = [
-        pl.BlockSpec((1, g, d), lambda i, j, pt_ref: (i, 0, 0)),
+        pl.BlockSpec((1, g, d), lambda i, j, pt_ref, qp_ref: (i, 0, 0)),
         pl.BlockSpec((1, 1, ps, d), page_idx),
     ]
     inputs = [q4, kq]
@@ -134,21 +136,22 @@ def paged_decode_attention(
     if int8:
         in_specs.append(pl.BlockSpec((1, 1, ps, 1), page_idx))
         inputs.append(vs)
-    in_specs += [
-        pl.BlockSpec((1, ps), lambda i, j, pt_ref: (pt_ref[i // hkv, j], 0)),
-        pl.BlockSpec((1, 1), lambda i, j, pt_ref: (i, 0)),
-    ]
-    inputs += [ppos, qp]
+    # (n_pages, 1, ps): a page's (1, ps) position row spans the full
+    # second-minor extent
+    in_specs.append(pl.BlockSpec(
+        (1, 1, ps), lambda i, j, pt_ref, qp_ref: (pt_ref[i // hkv, j], 0, 0)))
+    inputs.append(ppos.reshape(n_pages, 1, ps))
 
     kernel = functools.partial(_kernel, scale=scale, window=window,
-                               n_pages_grid=mp, int8=int8)
+                               n_pages_grid=mp, int8=int8, hkv=hkv)
     o = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(b * hkv, mp),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, g, d), lambda i, j, pt_ref: (i, 0, 0)),
+            out_specs=pl.BlockSpec((1, g, d),
+                                   lambda i, j, pt_ref, qp_ref: (i, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((g, 1), F32),
                 pltpu.VMEM((g, 1), F32),
@@ -156,6 +159,6 @@ def paged_decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b * hkv, g, d), q.dtype),
-        interpret=interpret_mode() if interpret is None else interpret,
-    )(pt, *inputs)
+        interpret=interpret_mode(interpret),
+    )(pt, qpos.astype(jnp.int32), *inputs)
     return o.reshape(b, hq, d)

@@ -57,7 +57,7 @@ def quantize_rows(x: jax.Array, bm: int = 8, interpret: bool | None = None):
             jax.ShapeDtypeStruct((m, d), jnp.int8),
             jax.ShapeDtypeStruct((m, 1), jnp.float32),
         ],
-        interpret=interpret_mode() if interpret is None else interpret,
+        interpret=interpret_mode(interpret),
     )(x2)
     return q.reshape(orig_shape), s.reshape(*orig_shape[:-1], 1)
 
@@ -129,6 +129,6 @@ def requantize_i32(
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int8),
-        interpret=interpret_mode() if interpret is None else interpret,
+        interpret=interpret_mode(interpret),
     )(x2)
     return out.reshape(orig_shape)

@@ -95,5 +95,5 @@ def ssd_scan(
         out_specs=pl.BlockSpec((1, chunk, p), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), F32)],
-        interpret=interpret_mode() if interpret is None else interpret,
+        interpret=interpret_mode(interpret),
     )(x, dt, b, c, a)

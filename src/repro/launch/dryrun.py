@@ -21,10 +21,12 @@ Usage:
 """
 from __future__ import annotations
 
-# The placeholder-device flag MUST precede any other import (including
-# ``from repro...``): jax locks the device count on first init.  Only the
-# dry-run sets this — smoke tests and benches see 1 device.
+# The CPU platform pin and the placeholder-device flag MUST precede any
+# other import (including ``from repro...``): jax locks the platform and
+# device count on first init.  Only the dry-run sets this — smoke tests and
+# benches see 1 device, and on a TPU host the dry-run never opens the chip.
 import os  # noqa: E402
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
 
@@ -119,11 +121,7 @@ def run_pipeline_cell(n_stages: int = 4, n_microbatches: int = 8,
     assert n_layers % n_stages == 0, (n_layers, n_stages)
     from jax.sharding import PartitionSpec as P
 
-    from ..dist.pipeline import (
-        bubble_fraction,
-        pipeline_apply,
-        shard_map_compat,
-    )
+    from ..dist.pipeline import bubble_fraction, pipeline_apply
 
     mesh = jax.make_mesh((n_stages,), ("stage",))
 
@@ -133,8 +131,9 @@ def run_pipeline_cell(n_stages: int = 4, n_microbatches: int = 8,
     def run(stage_params, xs):
         return pipeline_apply(layer_fn, stage_params, xs, axis_name="stage")
 
-    fn = jax.jit(shard_map_compat(
-        run, mesh, in_specs=(P("stage"), P()), out_specs=P()))
+    fn = jax.jit(jax.shard_map(
+        run, mesh=mesh, in_specs=(P("stage"), P()), out_specs=P(),
+        check_vma=False))
     layers_abs = jax.ShapeDtypeStruct((n_layers, d_model, d_model),
                                       jnp.float32)
     xs_abs = jax.ShapeDtypeStruct((n_microbatches, microbatch, d_model),
@@ -315,8 +314,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x: one dict per computation
-        ca = ca[0] if ca else {}
     text = compiled.as_text()
     hlo = hlo_analysis.analyze(text)
     n_dev = mesh.size

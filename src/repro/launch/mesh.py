@@ -33,14 +33,9 @@ def _validate_axes(shape, axes) -> None:
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across versions: axis_types only exists on newer jax
-    (0.4.x infers Auto axes, which is what we want anyway)."""
     _validate_axes(shape, axes)
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -24,6 +24,7 @@ from ..data import DataConfig, TokenPipeline
 from ..dist.sharding import AxisEnv, set_axis_env
 from ..models import init_params
 from ..train import AdamWConfig, CheckpointManager, TrainConfig, Trainer
+from .compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -41,6 +42,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     set_axis_env(AxisEnv())  # single-host: no mesh binding

@@ -13,10 +13,11 @@ training.
 from __future__ import annotations
 
 import functools
-from typing import Any
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..dist.sharding import shard_hint
 from ..dist.tp import tp_row_shard, tp_row_unshard
@@ -43,31 +44,44 @@ def exec_mode(cfg: ArchConfig) -> ExecMode:
 # init
 # ---------------------------------------------------------------------------
 
-def init_params(key, cfg: ArchConfig) -> dict:
+def _stack(*xs):
+    return np.stack(xs) if isinstance(xs[0], np.ndarray) else jnp.stack(xs)
+
+
+def init_params(key, cfg: ArchConfig,
+                finish: Callable[[dict], dict] | None = None) -> dict:
+    """Random parameters from ``key``.
+
+    ``finish`` maps each freshly initialized block, and the dict of
+    top-level leaves, before blocks are stacked over periods — e.g. PTQ
+    plus a move to host memory, so that the full float tree never exists
+    at once.  Every transform that acts leaf by leaf on a layer gives the
+    same tree as applying it to the stacked result."""
+    finish = finish or (lambda tree: tree)
     ks = jax.random.split(key, cfg.n_layers + 4)
     pattern = cfg.block_pattern
     periods: list[Any] = []
     shared = None
     for pos, kind in enumerate(pattern):
         if kind == "shared_attn":
-            shared = init_block_params(ks[pos], "shared_attn", cfg)
+            shared = finish(init_block_params(ks[pos], "shared_attn", cfg))
             periods.append(None)  # placeholder; applied from params["shared"]
             continue
         stacked = [
-            init_block_params(ks[cfg.period * rep + pos], kind, cfg)
+            finish(init_block_params(ks[cfg.period * rep + pos], kind, cfg))
             for rep in range(cfg.n_periods)
         ]
-        periods.append(jax.tree.map(lambda *xs: jnp.stack(xs), *stacked))
-    params = {
+        periods.append(jax.tree.map(_stack, *stacked))
+    top = {
         "embed": embed_init(ks[-1], cfg.padded_vocab, cfg.d_model),
         "final_norm": norm_params(cfg.d_model, cfg.norm_type),
-        "periods": periods,
     }
-    if shared is not None:
-        params["shared"] = shared
     if not cfg.tie_embeddings:
         # stored (d_model, vocab): the lm-head layout ("unembed" spec rule)
-        params["unembed"] = embed_init(ks[-2], cfg.padded_vocab, cfg.d_model).T
+        top["unembed"] = embed_init(ks[-2], cfg.padded_vocab, cfg.d_model).T
+    params = {**finish(top), "periods": periods}
+    if shared is not None:
+        params["shared"] = shared
     return params
 
 

@@ -137,16 +137,18 @@ def ptq_quantize_params(params, policy: dict | None = None,
             group = _fit_group(int(x.shape[-2]), int(spec["group"]))
         if group is None:
             q = _quantize_leaf(x)
-            report[p] = {"class": cls, "bits": 8, "group": None,
-                         "clip": 1.0, **_scale_stats(q["scale"])}
+            if with_report:
+                report[p] = {"class": cls, "bits": 8, "group": None,
+                             "clip": 1.0, **_scale_stats(q["scale"])}
         else:
             clip = float(spec.get("clip", 1.0))
             q = quantize_weight_w4(x, group=group, clip_ratio=clip)
-            # effective per-group scales: column scale x int8 multiplier
-            eff = (q["scale"][..., None, :]
-                   * q["qmul"].astype(jnp.float32))
-            report[p] = {"class": cls, "bits": 4, "group": group,
-                         "clip": clip, **_scale_stats(eff)}
+            if with_report:
+                # effective per-group scales: column scale x int8 multiplier
+                eff = (q["scale"][..., None, :]
+                       * q["qmul"].astype(jnp.float32))
+                report[p] = {"class": cls, "bits": 4, "group": group,
+                             "clip": clip, **_scale_stats(eff)}
         leaves.append(q)
     tree = jax.tree_util.tree_unflatten(treedef, leaves)
     return (tree, report) if with_report else tree

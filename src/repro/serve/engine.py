@@ -110,6 +110,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels import ops
 from ..models import ArchConfig, forward, init_states, precompute_cross_states
 from ..models.attention import gather_pages, rollback_cache, scatter_pages
 from .draft import ngram_propose
@@ -276,7 +277,9 @@ class ServingEngine:
 
     def __init__(self, params, cfg: ArchConfig, serve_cfg: ServeConfig,
                  kv_source=None):
-        self.params = params
+        # host-resident weights land on the default device here; under TP
+        # _init_tp places them sharded instead (never whole on one chip)
+        self.params = params if serve_cfg.tp > 1 else jax.device_put(params)
         self.cfg = cfg
         self.scfg = serve_cfg
         self.kv_source = kv_source
@@ -331,6 +334,7 @@ class ServingEngine:
 
         def _packed_masked(params, tokens, positions, states, lane_mask,
                            last_idx, commit_all, verify_rows):
+            ops.check_serving_backend()
             logits, new_states = forward(params, cfg, tokens,
                                          positions=positions, states=states,
                                          kv_source=kv_source)
@@ -431,10 +435,9 @@ class ServingEngine:
         annotations, so GSPMD re-partitions them over whatever sharding
         the state tree carries, and ``_gather_pages_host``'s device_get
         assembles full pages from the shards (replication-safe)."""
-        from ..dist.pipeline import shard_map_compat
         from ..dist.sharding import serve_param_specs, serve_state_specs
         from ..dist.tp import TPServing, tp_serving, validate_tp_serving
-        from ..kernels import autotune, ops
+        from ..kernels import autotune
         from ..launch.mesh import make_tp_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -467,10 +470,10 @@ class ServingEngine:
                     return packed_masked(params, tokens, positions, states,
                                          lane_mask, last_idx, commit_all,
                                          verify_rows)
-            f = shard_map_compat(
-                inner, mesh,
+            f = jax.shard_map(
+                inner, mesh=mesh,
                 in_specs=(pspecs, P(), P(), sspecs, P(), P()),
-                out_specs=(P(), P(), sspecs))
+                out_specs=(P(), P(), sspecs), check_vma=False)
             return f(params, tokens, positions, states, lane_mask, last_idx)
 
         self._step_fn = jax.jit(_sharded, static_argnums=(6, 7))
@@ -588,22 +591,32 @@ class ServingEngine:
         # bucket-1 program (the all-decode steady state / tokenwise row)
         self._submit_warmup([2], 1)
         self.run_until_drained()
-        b = self.scfg.batch_lanes
         # bucket 1 always participates even when absent from the table
         # (chunked mode): the all-lanes-DECODING steady state is the
         # dominant production program
         for t in sorted({1, *self._buckets}):
-            _, _, self.states = self._step_fn(
-                self.params, jnp.zeros((b, t), jnp.int32),
-                jnp.full((b, t), -1, jnp.int32), self.states,
-                jnp.ones((b,), bool), jnp.zeros((b,), jnp.int32), True,
-                min(self._spec_k + 1, t))
+            _, _, self.states = self._step_fn(*self._all_pad_args(t))
         if self._paged:
             # warmup prompts must not linger as shareable prefixes (or hold
             # pages): flush the radix index before real traffic arrives
             self._apply_pool_actions(self.pool.flush_tree())
         self.finished.clear()
         self.reset_stats()
+
+    def _all_pad_args(self, t: int) -> tuple:
+        """Arguments of the all-lanes (``commit_all``) step at bucket ``t``
+        with every position -1: cache writes are dropped, so running it
+        leaves the committed states unchanged."""
+        b = self.scfg.batch_lanes
+        return (self.params, jnp.zeros((b, t), jnp.int32),
+                jnp.full((b, t), -1, jnp.int32), self.states,
+                jnp.ones((b,), bool), jnp.zeros((b,), jnp.int32), True,
+                min(self._spec_k + 1, t))
+
+    def step_hlo(self, t: int) -> str:
+        """Compiled HLO text of the all-lanes packed step at bucket ``t``
+        (e.g. to count the Pallas kernels in it: ``tpu_custom_call``)."""
+        return self._step_fn.lower(*self._all_pad_args(t)).compile().as_text()
 
     def _submit_warmup(self, prompt: list[int], bucket: int) -> None:
         """Queue a warmup request keyed in the reserved stream space at the

@@ -234,7 +234,9 @@ class TestMeasuredCache:
         autotune.record("k", (8, 128, 128), 5.0)
         autotune.record("k", (16, 128, 128), 9.0)   # slower: ignored
         with open(autotune.cache_path()) as f:
-            assert json.load(f)["k"]["blocks"] == [8, 128, 128]
+            stored = json.load(f)
+        assert list(stored) == [autotune.measured_key("k")]
+        assert stored[autotune.measured_key("k")]["blocks"] == [8, 128, 128]
 
     def test_measure_times_candidates(self, tmp_path, monkeypatch):
         monkeypatch.setenv("REPRO_AUTOTUNE_CACHE",
@@ -268,11 +270,13 @@ class TestSweepRunner:
         import json
         cache = json.loads((tmp_path / "measured.json").read_text())
         for key in keys:
-            assert "blocks" in cache[key] and "us" in cache[key]
+            ent = cache[autotune.measured_key(key)]
+            assert "blocks" in ent and "us" in ent
         # the lookup path consumes what the sweep wrote
         dec = next(k for k in keys if k.startswith("decode/"))
         s, d, g = (int(v) for v in dec.split("/")[1].split("x"))
-        assert autotune.decode_blocks(s, d, g) == cache[dec]["blocks"][0]
+        assert autotune.decode_blocks(s, d, g) == \
+            cache[autotune.measured_key(dec)]["blocks"][0]
         autotune.reset_measured_cache()
 
 

@@ -1,9 +1,8 @@
 """Property tests for dist/compression.py (error-feedback int8 grads).
 
 tests/test_dist.py covers the fixed-seed happy path; this file drives the
-compressor with RANDOMIZED magnitudes, shapes, and step counts (hypothesis
-when the container has it, the seeded ``_hypothesis_compat`` shim
-otherwise) and checks the two invariants the trainer actually relies on:
+compressor with RANDOMIZED magnitudes, shapes, and step counts
+(hypothesis) and checks the two invariants the trainer actually relies on:
 
   round trip   decompress(compress(g)) stays within half an int8 grid
                step of g at EVERY magnitude, and the residual is exactly
@@ -15,10 +14,7 @@ otherwise) and checks the two invariants the trainer actually relies on:
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:  # container lacks hypothesis: seeded fallback
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.dist.compression import (
     compress_grads,

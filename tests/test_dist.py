@@ -22,7 +22,7 @@ KEY = jax.random.PRNGKey(0)
 _PROD_ENV = AxisEnv(dp=("data",), fsdp=("data",), tp=("model",),
                     ep=("model",), sp=("model",), active=True,
                     sizes=(("data", 16), ("model", 16)))
-_PROD_MESH = shd.AbstractMesh((("data", 16), ("model", 16)))
+_PROD_MESH = shd.AbstractMesh((16, 16), ("data", "model"))
 
 
 def _abstract_params(arch):

@@ -246,12 +246,15 @@ class TestInt8AttentionPVDequant:
         return q, k, v, jnp.asarray(vs, jnp.float32), sc, s_score
 
     def test_bit_match_vs_composition_oracle_single_block(self, rng):
-        """One KV block (bk == Skv): the fused PV-dequant pass is
-        BIT-IDENTICAL to the jnp composition oracle (same f32 sums)."""
+        """One block (bq == S, bk == Skv): the fused PV-dequant pass is
+        BIT-IDENTICAL to the jnp composition oracle (same f32 sums).  The
+        query block spans S too: XLA:CPU's f32 dot picks its summation
+        order by operand shape, so a (S/2, Skv) x (Skv, D) product need
+        not match the same rows of an (S, Skv) x (Skv, D) one."""
         from repro.kernels.int8_flash_attention import int8_flash_attention
         q, k, v, vs, _, s_score = self._quant(rng, 2, 4, 2, 64, 32)
         got = int8_flash_attention(q, k, v, s_score, causal=True,
-                                   v_scale=vs, bq=32, bk=64, interpret=True)
+                                   v_scale=vs, bq=64, bk=64, interpret=True)
         want = ref.int8_flash_attention_ref(q, k, v, s_score, causal=True,
                                             v_scale=vs)
         assert got.dtype == jnp.float32
@@ -738,10 +741,7 @@ class TestSSDScan:
 # W4A8: int4 pack/unpack container + packed GEMM family
 # ---------------------------------------------------------------------------
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:            # container image has no hypothesis:
-    from _hypothesis_compat import given, settings, st  # seeded-rng shim
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.quantize import pack_int4, unpack_int4
 
@@ -750,7 +750,8 @@ class TestInt4Pack:
     """Property: pack_int4/unpack_int4 roundtrip and match the independent
     modular-arithmetic oracle — odd K, negatives, group boundaries."""
 
-    @settings(max_examples=40)
+    # deadline=None: the first example compiles JAX
+    @settings(max_examples=40, deadline=None)
     @given(st.integers(1, 131), st.integers(1, 24))
     def test_roundtrip_property(self, k, n):
         rng = np.random.default_rng(k * 1000 + n)

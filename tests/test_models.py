@@ -199,6 +199,23 @@ def test_int8_kv_cache_close_to_bf16():
     assert float(jnp.max(jnp.abs(got - full.astype(jnp.float32)))) < 0.6
 
 
+@pytest.mark.parametrize("arch,policy", [
+    ("starcoder2-3b", None), ("codeqwen1.5-7b", "w4"), ("zamba2-2.7b", None)])
+def test_init_finish_per_block_matches_stacked(arch, policy):
+    """``init_params(finish=PTQ)`` quantizes block by block (the serving
+    init never holds the full float tree) and equals PTQ of the stacked
+    float tree, leaf for leaf."""
+    from repro.quant.ptq import DEFAULT_W4_POLICY
+    cfg = get_config(arch, reduced=True)
+    pol = DEFAULT_W4_POLICY if policy == "w4" else None
+    want = ptq_quantize_params(init_params(KEY, cfg), policy=pol)
+    got = init_params(KEY, cfg,
+                      finish=lambda t: ptq_quantize_params(t, policy=pol))
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype and (np.asarray(a) == np.asarray(b)).all()
+
+
 def test_w8a8_quality_vs_bf16():
     """PTQ W8A8 must stay close to the float model (random init)."""
     cfg16 = get_config("codeqwen1.5-7b", reduced=True)

@@ -6,12 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.pipeline import (
-    bubble_fraction,
-    pipeline_apply,
-    shard_map_compat,
-    split_stages,
-)
+from repro.dist.pipeline import bubble_fraction, pipeline_apply, split_stages
 
 
 def test_split_stages_shapes():
@@ -39,8 +34,9 @@ def test_single_stage_schedule_matches_direct():
         return pipeline_apply(layer_fn, stage_params, xs, axis_name="pod")
 
     with mesh:
-        out = jax.jit(shard_map_compat(
-            run, mesh, in_specs=(P(), P()), out_specs=P()))(layers, x)
+        out = jax.jit(jax.shard_map(
+            run, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+            check_vma=False))(layers, x)
 
     def direct(h):
         for i in range(3):

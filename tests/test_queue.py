@@ -10,10 +10,7 @@ import math
 
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.serve.queue import AdmissionQueue, QueueFullError, percentile
 

@@ -218,13 +218,12 @@ def test_tp_serving_overlap_choice():
         assert autotune.tp_serving_overlap(
             64, 128, 128, 128, 8, backend="jnp") in ("overlap", "barrier")
         # a measured key overrides the cost-model seed
-        autotune._MEASURED = {
-            "tpserve/64x128x128x128/tp8/jnp": {"blocks": [1], "us": 1.0}}
+        key = autotune.measured_key("tpserve/64x128x128x128/tp8/jnp")
+        autotune._MEASURED = {key: {"blocks": [1], "us": 1.0}}
         autotune.tp_serving_overlap.cache_clear()
         assert autotune.tp_serving_overlap(
             64, 128, 128, 128, 8, backend="jnp") == "overlap"
-        autotune._MEASURED["tpserve/64x128x128x128/tp8/jnp"] = {
-            "blocks": [0], "us": 1.0}
+        autotune._MEASURED[key] = {"blocks": [0], "us": 1.0}
         autotune.tp_serving_overlap.cache_clear()
         assert autotune.tp_serving_overlap(
             64, 128, 128, 128, 8, backend="jnp") == "barrier"
