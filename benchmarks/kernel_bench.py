@@ -87,17 +87,14 @@ def run(backend: str = "jnp", smoke: bool = False,
     assert backend in ("jnp", "pallas"), backend
     # interpret mode follows the platform: the pallas backend runs
     # interpreted on CPU (correctness timing) and compiled on a TPU
-    prev_backend = ops.set_backend(backend)
     # interpret mode is slow: shrink the sweep so --backend pallas stays
     # usable as a correctness-timing smoke rather than a coffee break
     small = smoke or backend == "pallas"
     reps = 1 if small else 3
     if strict is None:
         strict = not smoke
-    try:
+    with ops.use_backend(backend):
         return _run_rows(small, reps, backend, strict)
-    finally:
-        ops.set_backend(prev_backend)
 
 
 def _run_rows(small: bool, reps: int, backend: str,
@@ -684,14 +681,11 @@ def main() -> None:
         from repro.kernels.common import interpret_mode
         # interpret mode follows the platform: compiled on a TPU, so the
         # sweep times hardware kernels there; interpreted on CPU
-        prev_b = _ops.set_backend(args.backend)
-        try:
+        with _ops.use_backend(args.backend):
             fams = tuple(f for f in args.families.split(",") if f)
             print(f"sweep: backend={args.backend} "
                   f"interpret={interpret_mode()}", file=sys.stderr)
             keys = sweep(backend=args.backend, families=fams)
-        finally:
-            _ops.set_backend(prev_b)
         from repro.kernels import autotune
         print(f"recorded {len(keys)} keys -> {autotune.cache_path()}")
         return

@@ -30,19 +30,34 @@ import os
 import sys
 import time
 
-# Bound on max |pallas - jnp| over the compared last-row logits, as a
-# fraction of the reference's largest |logit|.  Prefill runs the integer
-# GEMM/norm kernels, which match their jnp oracles bit for bit.  The decode
-# token adds the paged int8 decode kernel, which dequantizes the KV cache in
-# f32 while the jnp path dequantizes it to bf16 before attending; the same
-# pair is held to 1e-2 on O(1) logits of a 2-layer bf16 model by
-# tests/test_models.py::test_int8_kv_decode_kernel_path_matches_fallback.
-# Under W8A8 the attention output is re-quantized to int8, where a bf16-ulp
-# difference can flip one rounding step.  CPU interpret-mode runs of this
-# comparison (d_model 64 and 256; 2, 8 and 30 layers) gave 3.0-4.5% of the
-# logit scale, flat in depth; a kernel that computes the wrong thing moves
-# logits by their whole scale.
-LOGIT_REL_TOL = 0.1
+# Pallas path vs the jnp reference on the chip, over one fixed packed batch
+# (a 64-token prefill on every lane, then one decode token), in fractions
+# of the reference's largest |logit|.
+#
+# Prefill runs the integer kernels (quant_rows, the W8A8 GEMM epilogues,
+# layernorm_i8), each the same arithmetic as its jnp oracle, and on CPU
+# the two paths give identical logits.  On the chip they differed (0.2177
+# at a logit scale of 5.0128): XLA turns a division by a constant into a
+# multiply by the f32 reciprocal while Mosaic divides.  Kernels and oracles
+# now multiply by that reciprocal themselves (``kernels.common.recip``),
+# which should make the chip's prefill bit-identical too; until a chip run
+# shows it, the bound admits the gap measured before that repair.  Any
+# single int8 rounding flip moves these logits by several percent of their
+# scale, so the bound separates exact from inexact, not degrees of error.
+PREFILL_REL_TOL = 0.06
+# The decode token adds the paged int8 decode kernel, which dequantizes
+# the KV cache in f32 where the jnp path rounds K/V to bf16 before
+# attending.  W8A8 re-quantizes the attention output to int8, so a
+# rounding flip there moves the logits like a prefill flip does: the chip
+# gave 4.4% before the prefill repair.  The kernel itself is held tightly
+# against its f32 oracle on its own (DECODE_KERNEL_REL_TOL), and the bound
+# below is checked to sit well under the logit spread between lanes, which
+# is what a decode step reading the wrong cache would show.
+DECODE_REL_TOL = 0.1
+# paged decode kernel vs its oracle, same f32 inputs at the engine's
+# widths, both contracting at f32 precision: summation order and exp
+# rounding only (1.1e-6 on CPU).  bf16 passes would give ~1e-3.
+DECODE_KERNEL_REL_TOL = 1e-4
 
 STARCODER = ["--arch", "starcoder2-3b", "--lanes", "4", "--token-budget",
              "128", "--max-seq", "512", "--max-new", "32"]
@@ -127,56 +142,84 @@ def peak_bytes(label: str) -> None:
           f"{stats.get('peak_bytes_in_use', 'not reported')}", flush=True)
 
 
-def reference_gap(engine, seed: int, t: int = 64) -> float:
-    """Largest |pallas - jnp| of the last-row logits over one fixed packed
-    batch: a ``t``-token prefill on every lane, then one decode token (the
-    paged int8 decode kernel's step), each program compiled once per
-    kernel backend over the engine's own weights and KV layout."""
+def reference_gap(engine, seed: int, t: int = 64) -> None:
+    """Last-row logits of the Pallas path vs the jnp reference over one
+    fixed packed batch, each compiled over the engine's own weights and KV
+    layout (``ServingEngine.fixed_batch_logits``): a ``t``-token prefill
+    on every lane, then one decode token."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    b, vocab = engine.scfg.batch_lanes, engine.cfg.vocab_size
+    rng = np.random.default_rng(seed)
+    tokens = jnp.asarray(rng.integers(2, vocab, (b, t)), jnp.int32)
+    batches = [(tokens, jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32),
+                                         (b, t))),
+               (tokens[:, -1:], jnp.full((b, 1), t, jnp.int32))]
+    got, want = (np.asarray(jnp.stack(engine.fixed_batch_logits(
+        batches, backend)), np.float32) for backend in ("pallas", "jnp"))
+    check(np.isfinite(got).all() and np.isfinite(want).all(),
+          "non-finite logits in the reference comparison")
+    scale = float(np.abs(want).max())
+    agree = float((got.argmax(-1) == want.argmax(-1)).mean())
+    # what a decode step that read another lane's cache would be off by
+    spread = float(np.abs(want[1] - np.roll(want[1], 1, axis=0)).max())
+    gaps = {}
+    for i, (phase, tol) in enumerate((("prefill", PREFILL_REL_TOL),
+                                      ("decode", DECODE_REL_TOL))):
+        gaps[phase] = float(np.abs(got[i] - want[i]).max())
+        same = bool((got[i] == want[i]).all())
+        print(f"[reference] fixed packed batch {b}x{t}, {phase}: "
+              f"{'bit-identical' if same else 'not bit-identical'}, max "
+              f"|pallas - jnp| last-row logit {gaps[phase]!r}, bound "
+              f"{tol} x scale {scale!r} = {tol * scale!r}", flush=True)
+        check(gaps[phase] <= tol * scale,
+              f"{phase}: pallas vs jnp logit gap {gaps[phase]} > "
+              f"{tol} x {scale}")
+    print(f"[reference] argmax agreement {agree!r}; decode logit spread "
+          f"between lanes {spread!r}", flush=True)
+    check(spread >= 3 * DECODE_REL_TOL * scale,
+          f"decode bound {DECODE_REL_TOL} x {scale} does not separate a "
+          f"wrong cache (lane spread {spread})")
+
+
+def decode_kernel_gap(engine, seed: int) -> None:
+    """The paged int8 decode kernel vs its f32 oracle on the chip, at the
+    engine's widths and page geometry: every lane holds a full page
+    chain, at four different depths."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from repro.kernels import ops
-    from repro.models import init_states
-    from repro.serve.engine import _with_page_table, packed_step
 
-    cfg, scfg, pool = engine.cfg, engine.scfg, engine.pool
-    b = scfg.batch_lanes
+    cfg, pool = engine.cfg, engine.pool
+    b, hq, hkv, d = (engine.scfg.batch_lanes, cfg.n_heads, cfg.n_kv_heads,
+                     cfg.head_dim)
+    n, ps, mp = pool.n, pool.ps, pool.mp
     rng = np.random.default_rng(seed)
-    tokens = jnp.asarray(rng.integers(2, cfg.vocab_size, (b, t)), jnp.int32)
-    pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-    pt = jnp.asarray(1 + np.arange(b * pool.mp).reshape(b, pool.mp),
-                     jnp.int32)            # lane i owns its own pages
-    states = _with_page_table(
-        init_states(cfg, b, scfg.max_seq, int8_kv=scfg.int8_kv,
-                    paged_pages=pool.n, page_size=pool.ps), pt)
-
-    def run(backend):
-        prev = ops.set_backend(backend)
-        try:
-            # a fresh jit per backend: the backend is read while tracing
-            step = jax.jit(lambda p, x, q, s: packed_step(p, cfg, x, q, s))
-            lg_prefill, st = step(engine.params, tokens, pos, states)
-            lg_decode, _ = step(engine.params, tokens[:, -1:],
-                                jnp.full((b, 1), t, jnp.int32), st)
-            return np.asarray(jnp.stack([lg_prefill, lg_decode]), np.float32)
-        finally:
-            ops.set_backend(prev)
-
-    got, want = run("pallas"), run("jnp")
-    check(np.isfinite(got).all() and np.isfinite(want).all(),
-          "non-finite logits in the reference comparison")
-    gaps = np.abs(got - want).max(axis=(1, 2))       # (prefill, decode)
-    scale = float(np.abs(want).max())
-    agree = float((got.argmax(-1) == want.argmax(-1)).mean())
-    print(f"[reference] fixed packed batch {b}x{t} prefill + 1 decode: "
-          f"max |pallas - jnp| last-row logit {float(gaps[0])!r} (prefill), "
-          f"{float(gaps[1])!r} (decode); reference logit scale {scale!r}; "
-          f"tolerance {LOGIT_REL_TOL} x scale = {LOGIT_REL_TOL * scale!r}; "
-          f"argmax agreement {agree!r}", flush=True)
-    gap = float(gaps.max())
-    check(gap <= LOGIT_REL_TOL * scale,
-          f"pallas vs jnp logit gap {gap} > {LOGIT_REL_TOL} x {scale}")
-    return gap
+    arena = [jnp.asarray(rng.integers(-127, 128, (n, ps, hkv, d)), jnp.int8)
+             if i % 2 == 0 else jnp.asarray(
+                 rng.uniform(1e-3, 2e-2, (n, ps, hkv, 1)), jnp.float32)
+             for i in range(4)]                      # pk, pks, pv, pvs
+    ppos = np.full((n, ps), -1, np.int32)
+    ppos[1:1 + b * mp] = np.arange(mp * ps).reshape(mp, ps)[None].repeat(
+        b, 0).reshape(b * mp, ps)
+    pt = jnp.asarray(1 + np.arange(b * mp).reshape(b, mp), jnp.int32)
+    qpos = jnp.asarray(np.linspace(ps, mp * ps - 1, b).astype(np.int32))
+    q = jnp.asarray(rng.normal(size=(b, hq, d)), jnp.float32)
+    out = {}
+    for backend in ("pallas", "jnp"):
+        with ops.use_backend(backend):
+            out[backend] = np.asarray(jax.jit(ops.paged_attention_decode)(
+                q, *arena, jnp.asarray(ppos), pt, qpos))
+    gap = float(np.abs(out["pallas"] - out["jnp"]).max())
+    scale = float(np.abs(out["jnp"]).max())
+    print(f"[reference] paged int8 decode kernel vs oracle ({b} lanes, "
+          f"{hq}/{hkv} heads, d {d}, {mp} pages of {ps}): max gap {gap!r}, "
+          f"bound {DECODE_KERNEL_REL_TOL} x scale {scale!r}", flush=True)
+    check(gap <= DECODE_KERNEL_REL_TOL * scale,
+          f"paged decode kernel vs oracle gap {gap} > "
+          f"{DECODE_KERNEL_REL_TOL} x {scale}")
 
 
 def one_chip(seed: int, base: list[str] = STARCODER) -> None:
@@ -194,6 +237,7 @@ def one_chip(seed: int, base: list[str] = STARCODER) -> None:
         check(n > 0, f"no Pallas kernel in the compiled bucket-{t} step")
     peak_bytes("A w8a8 int8-paged")
     reference_gap(engine, seed)
+    decode_kernel_gap(engine, seed)
     del engine, out_a
     gc.collect()
 

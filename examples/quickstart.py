@@ -34,15 +34,14 @@ from repro.core import inumerics as inum
 from repro.kernels import ops, ref
 
 # the Pallas kernel: interpreted on CPU, compiled on a TPU
-prev_backend = ops.set_backend("pallas")
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.integers(-127, 128, (64, 128)), jnp.int8)
 w = jnp.asarray(rng.integers(-127, 128, (128, 96)), jnp.int8)
 rq = inum.compute_requant_params(1e-3, 128 * 127 * 127)
-exact = bool((ops.gemm_i8(x, w, requant=rq)
-              == ref.int8_gemm_ref(x, w, requant=rq)).all())
+with ops.use_backend("pallas"):
+    exact = bool((ops.gemm_i8(x, w, requant=rq)
+                  == ref.int8_gemm_ref(x, w, requant=rq)).all())
 print(f"[Pallas] int8 GEMM + requant epilogue bit-exact vs oracle: {exact}")
-ops.set_backend(prev_backend)
 
 # --- 3. W8A8 transformer -----------------------------------------------------
 from repro.configs import get_config

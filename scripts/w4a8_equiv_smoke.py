@@ -85,8 +85,7 @@ def drain(params, cfg) -> dict:
 
 
 def main() -> None:
-    prev = ops.set_backend("pallas")
-    try:
+    with ops.use_backend("pallas"):
         for arch in ("starcoder2-3b", "codeqwen1.5-7b"):
             cfg = get_config(arch, precision="w4a8", reduced=True)
             params = ptq_quantize_params(
@@ -104,8 +103,6 @@ def main() -> None:
             print(f"w4a8 equivalence OK ({arch}): {len(REQS)} requests "
                   f"bit-identical fused vs unfused "
                   f"({sum(len(t) for t in got.values())} tokens)")
-    finally:
-        ops.set_backend(prev)
 
 
 if __name__ == "__main__":

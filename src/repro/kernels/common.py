@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # TPU tiling constants
 LANE = 128          # last-dim tile (VREG lane width, MXU edge)
@@ -53,6 +54,15 @@ def interpret_mode(interpret: bool | None = None) -> bool:
     if interpret:
         _refuse_interpret_on_tpu()
     return bool(interpret)
+
+
+def recip(c: float) -> np.float32:
+    """f32 reciprocal of a static scale.  Kernels and their oracles multiply
+    by it rather than divide by the scale: XLA rewrites a division by a
+    constant into exactly this multiply, while Mosaic divides, so only the
+    explicit multiply gives the compiled kernel and the XLA path the same
+    bits."""
+    return np.float32(1) / np.float32(c)
 
 
 def pad_to(x: jax.Array, multiples: tuple[int, ...], value=0) -> jax.Array:

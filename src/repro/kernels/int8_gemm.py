@@ -51,7 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.inumerics import RequantParams
-from .common import interpret_mode, requant_block
+from .common import interpret_mode, recip, requant_block
 from .int_gelu import gelu_block, gelu_out_scale, gelu_requant_params
 from .int_silu import silu_block, silu_out_scale
 
@@ -108,7 +108,7 @@ def _kernel(*refs, n_k: int, epilogue: str, s1: int, mult: int, s2: int,
                 # the unfused path quantizes the bf16 residual stream: keep
                 # the same grid so fused == unfused bit-for-bit
                 h = h.astype(stream_dtype).astype(F32)
-                q = jnp.clip(jnp.round(h / gelu_scale), -128, 127).astype(I32)
+                q = jnp.clip(jnp.round(h * recip(gelu_scale)), -128, 127).astype(I32)
                 out_ref[...] = gelu_block(
                     q, scale=gelu_scale, s1=g_s1, mult=g_mult,
                     s2=g_s2).astype(jnp.int8)
@@ -256,7 +256,7 @@ def _dual_kernel(*refs, n_k: int, act: str, act_scale: float,
                  ).astype(stream_dtype)
             g = (acc_g[...].astype(F32) * xs_ref[...] * gs_ref[...]
                  ).astype(stream_dtype).astype(F32)
-            q = jnp.clip(jnp.round(g / act_scale), -128, 127).astype(I32)
+            q = jnp.clip(jnp.round(g * recip(act_scale)), -128, 127).astype(I32)
             if act == "silu":
                 a = (silu_block(q, scale=act_scale).astype(F32)
                      * silu_out_scale(act_scale)).astype(stream_dtype)
@@ -424,7 +424,7 @@ def _w4a8_kernel(*refs, n_k: int, epilogue: str, gelu_scale: float,
             h = h + b_ref[...]
         if epilogue == "scaled_gelu":
             h = h.astype(stream_dtype).astype(F32)
-            q = jnp.clip(jnp.round(h / gelu_scale), -128, 127).astype(I32)
+            q = jnp.clip(jnp.round(h * recip(gelu_scale)), -128, 127).astype(I32)
             out_ref[...] = gelu_block(
                 q, scale=gelu_scale, s1=g_s1, mult=g_mult,
                 s2=g_s2).astype(jnp.int8)
@@ -560,7 +560,7 @@ def _dual_w4a8_kernel(*refs, n_k: int, act: str, act_scale: float,
              * xs_ref[...]).astype(stream_dtype)
         g = (acc_g[...].astype(F32) * gs_ref[...]
              * xs_ref[...]).astype(stream_dtype).astype(F32)
-        q = jnp.clip(jnp.round(g / act_scale), -128, 127).astype(I32)
+        q = jnp.clip(jnp.round(g * recip(act_scale)), -128, 127).astype(I32)
         if act == "silu":
             a = (silu_block(q, scale=act_scale).astype(F32)
                  * silu_out_scale(act_scale)).astype(stream_dtype)

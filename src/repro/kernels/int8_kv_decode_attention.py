@@ -28,6 +28,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .common import interpret_mode
 
 F32 = jnp.float32
+HIGHEST = jax.lax.Precision.HIGHEST
 NEG = -1e30
 
 
@@ -48,7 +49,10 @@ def _kernel(qpos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref, o_ref,
     kpos = pos_ref[0]                            # (1, bk) absolute positions
     qpos = qpos_ref[i // hkv]                    # this sequence's step (SMEM)
 
+    # f32 operands at f32 precision, like the oracle (a TPU dot otherwise
+    # rounds f32 operands to bf16)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=HIGHEST,
                             preferred_element_type=F32) * scale  # (G, bk)
     valid = (kpos >= 0) & (kpos <= qpos)
     if window:
@@ -61,7 +65,8 @@ def _kernel(qpos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref, o_ref,
     alpha = jnp.exp(m_prev - m_new)
     l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=F32)
+        p, v, (((1,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=F32)
     m_scr[...] = m_new
 
     @pl.when(j == n_kv - 1)

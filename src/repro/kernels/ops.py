@@ -9,9 +9,11 @@ The kernel backend is resolved from the platform at first use, never at
 import: "pallas" (compiled kernels) on a TPU, "jnp" (the XLA reference
 path in ``ref.py``) anywhere else — e.g. CPU tests and the 512-device
 dry-run, where interpret-mode execution would be prohibitive.
-``set_backend`` is the hook tests and reference comparisons use; the
-serving engine refuses to trace its step on a TPU with the jnp path
-selected (``check_serving_backend``).
+``set_backend`` / ``use_backend`` are the hooks tests, reference
+comparisons and the trainer use (no kernel defines a VJP, so the
+differentiated forward traces the jnp path); the serving engine refuses
+to trace its step on a TPU with the jnp path selected
+(``check_serving_backend``).
 
 Fused epilogue entry points (``gemm_i8_gelu``, ``gemm_i8_add``,
 ``gemm_w8a8``, the dual-GEMM ``gated_mlp``/``gated_mlp_w8a8``, and their
@@ -22,6 +24,8 @@ exact unfused compositions, so both backends are bit-identical (the
 float ``gated_mlp`` matches to accumulation order, like flash attention).
 """
 from __future__ import annotations
+
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +54,17 @@ def set_backend(name: str | None) -> str | None:
         raise ValueError(f"unknown kernel backend {name!r}")
     prev, _BACKEND[0] = _BACKEND[0], name
     return prev
+
+
+@contextlib.contextmanager
+def use_backend(name: str | None):
+    """``set_backend(name)`` for the ``with`` block.  The backend is read
+    while tracing, so this scopes the programs traced inside it."""
+    prev = set_backend(name)
+    try:
+        yield
+    finally:
+        set_backend(prev)
 
 
 def backend() -> str:

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..core.inumerics import RequantParams
-from .common import interpret_mode
+from .common import interpret_mode, recip
 
 I32 = jnp.int32
 
@@ -31,7 +31,7 @@ I32 = jnp.int32
 def _quant_kernel(x_ref, out_ref, scale_ref):
     x = x_ref[...].astype(jnp.float32)
     amax = jnp.maximum(jnp.max(jnp.abs(x), axis=-1, keepdims=True), 1e-8)
-    scale = amax / 127.0
+    scale = amax * recip(127.0)
     q = jnp.round(x / scale)
     out_ref[...] = jnp.clip(q, -128, 127).astype(jnp.int8)
     scale_ref[...] = scale

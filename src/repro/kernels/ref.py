@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core import inumerics as inum
+from .common import recip
 
 I32 = jnp.int32
 
@@ -56,7 +57,7 @@ def gemm_w8a8_ref(x_q, x_scale, w_q, w_scale, bias=None, residual=None,
         h = h + bias
     if gelu_scale is not None:
         h = h.astype(out_dtype).astype(jnp.float32)
-        q = jnp.clip(jnp.round(h / gelu_scale), -128, 127).astype(I32)
+        q = jnp.clip(jnp.round(h * recip(gelu_scale)), -128, 127).astype(I32)
         return int_gelu_ref(q, gelu_scale)
     h = h.astype(out_dtype)
     if residual is not None:
@@ -94,7 +95,7 @@ def gated_mlp_w8a8_ref(x_q, x_scale, w_up_q, up_scale, w_gate_q, gate_scale,
     h = gemm_w8a8_ref(x_q, x_scale, w_up_q, up_scale, out_dtype=out_dtype)
     g = gemm_w8a8_ref(x_q, x_scale, w_gate_q, gate_scale,
                       out_dtype=out_dtype)
-    q = jnp.clip(jnp.round(g.astype(jnp.float32) / act_scale),
+    q = jnp.clip(jnp.round(g.astype(jnp.float32) * recip(act_scale)),
                  -128, 127).astype(I32)
     if act == "silu":
         a = (int_silu_ref(q, act_scale).astype(jnp.float32)
@@ -160,7 +161,7 @@ def gemm_w4a8_ref(x_q, x_scale, w4, qmul, w_scale, bias=None, residual=None,
         h = h + bias
     if gelu_scale is not None:
         h = h.astype(out_dtype).astype(jnp.float32)
-        q = jnp.clip(jnp.round(h / gelu_scale), -128, 127).astype(I32)
+        q = jnp.clip(jnp.round(h * recip(gelu_scale)), -128, 127).astype(I32)
         return int_gelu_ref(q, gelu_scale)
     h = h.astype(out_dtype)
     if residual is not None:
@@ -181,7 +182,7 @@ def gated_mlp_w4a8_ref(x_q, x_scale, up4, up_mul, up_scale, gate4, gate_mul,
                       out_dtype=out_dtype)
     g = gemm_w4a8_ref(x_q, x_scale, gate4, gate_mul, gate_scale,
                       out_dtype=out_dtype)
-    q = jnp.clip(jnp.round(g.astype(jnp.float32) / act_scale),
+    q = jnp.clip(jnp.round(g.astype(jnp.float32) * recip(act_scale)),
                  -128, 127).astype(I32)
     if act == "silu":
         a = (int_silu_ref(q, act_scale).astype(jnp.float32)
@@ -210,7 +211,7 @@ def int_gelu_ref(x, scale):
 
 def quantize_rows_ref(x):
     amax = jnp.maximum(jnp.max(jnp.abs(x), axis=-1, keepdims=True), 1e-8)
-    scale = amax / 127.0
+    scale = amax * recip(127.0)
     q = jnp.clip(jnp.round(x / scale), -128, 127).astype(jnp.int8)
     return q, scale.astype(jnp.float32)
 
@@ -259,13 +260,15 @@ def int8_kv_decode_attention_ref(q, k_q, k_s, v_q, v_s, pos_ids, qpos,
     v = (v_q.astype(jnp.float32) * v_s)
     g = hq // hkv
     qg = q.reshape(b, hkv, g, d).astype(jnp.float32)
-    s_ = jnp.einsum("bhgd,bshd->bhgs", qg, k) * scale
+    # f32 contractions at f32 precision (a TPU's default is bf16 passes)
+    hi = jax.lax.Precision.HIGHEST
+    s_ = jnp.einsum("bhgd,bshd->bhgs", qg, k, precision=hi) * scale
     valid = (pos_ids >= 0) & (pos_ids <= qpos[:, None])
     if window:
         valid &= pos_ids > (qpos[:, None] - window)
     s_ = jnp.where(valid[:, None, None, :], s_, -1e30)
     p = jax.nn.softmax(s_, axis=-1)
-    o = jnp.einsum("bhgs,bshd->bhgd", p, v)
+    o = jnp.einsum("bhgs,bshd->bhgd", p, v, precision=hi)
     return o.reshape(b, hq, d).astype(q.dtype)
 
 
@@ -295,13 +298,18 @@ def paged_decode_attention_ref(q, pk, pks, pv, pvs, ppos, pt, qpos,
     return jnp.where(live[:, None, None], out, 0)
 
 
-def int8_flash_attention_ref(q, k, v, scale, causal=True, v_scale=None):
+def int8_flash_attention_ref(q, k, v, scale, causal=True, v_scale=None,
+                             bq=None):
     """Bit-exact integer oracle of kernels.int8_flash_attention.
 
     With ``v_scale`` (per-(token, head) scales, [B,Hkv,Skv,1] f32) the PV
     contraction runs in f32 over the dequantized V rows and the result is
     the final attention output (acc / 127) — the exact composition the
-    fused PV-dequant pass must reproduce.
+    fused PV-dequant pass must reproduce.  ``bq`` contracts ``bq`` query
+    rows at a time, the kernel's own (bq, Skv) x (Skv, D) product: XLA:CPU
+    picks an f32 dot's summation order by the product's shape, so a row's
+    sum depends on how many rows share its product.  ``None`` contracts
+    all S rows at once.
     """
     b, h, s, d = q.shape
     _, hkv, skv, _ = k.shape
@@ -319,7 +327,12 @@ def int8_flash_attention_ref(q, k, v, scale, causal=True, v_scale=None):
     p = inum.i_softmax(sc, scale)  # int32 payload in [0,127]
     if v_scale is not None:
         vd = v.astype(jnp.float32) * v_scale                  # (B,H,Skv,D)
-        out = jnp.einsum("bhst,bhtd->bhsd", p.astype(jnp.float32), vd)
+        nq = 1 if bq is None else s // bq
+        pb = p.astype(jnp.float32).reshape(b, h, nq, s // nq, skv)
+        # V broadcast over the query blocks makes each block a product of
+        # its own (a free block dim would fold them back into one of S rows)
+        vb = jnp.broadcast_to(vd[:, :, None], (b, h, nq, skv, d))
+        out = jnp.einsum("bhnqt,bhntd->bhnqd", pb, vb).reshape(b, h, s, d)
         return out * (1.0 / 127.0)
     return jnp.einsum("bhst,bhtd->bhsd", p.astype(jnp.int8).astype(I32),
                       v.astype(I32))

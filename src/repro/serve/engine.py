@@ -321,20 +321,12 @@ class ServingEngine:
                 "attn", "moe", "shared_attn", "attn_swa", "moe_swa"}
             self._cap_window = (cfg.sliding_window if kinds and
                                 kinds <= {"attn_swa", "moe_swa"} else 0)
-            self.states = init_states(cfg, b, serve_cfg.max_seq,
-                                      int8_kv=serve_cfg.int8_kv,
-                                      window_slack=self._window_slack,
-                                      paged_pages=n_pages, page_size=ps)
             self._clear_fn = jax.jit(_paged_clear, donate_argnums=(0,))
             self._copy_fn = jax.jit(_paged_copy, donate_argnums=(0,))
-        else:
-            self.states = init_states(cfg, b, serve_cfg.max_seq,
-                                      int8_kv=serve_cfg.int8_kv,
-                                      window_slack=self._window_slack)
+        self.states = self._init_states()
 
-        def _packed_masked(params, tokens, positions, states, lane_mask,
-                           last_idx, commit_all, verify_rows):
-            ops.check_serving_backend()
+        def _step_body(params, tokens, positions, states, lane_mask,
+                       last_idx, commit_all, verify_rows):
             logits, new_states = forward(params, cfg, tokens,
                                          positions=positions, states=states,
                                          kv_source=kv_source)
@@ -351,6 +343,12 @@ class ServingEngine:
                 return lg[:, -1], greedy, new_states
             return (lg[:, -1], greedy,
                     _masked_commit(states, new_states, lane_mask))
+
+        def _packed_masked(*args):
+            ops.check_serving_backend()
+            return _step_body(*args)
+
+        self._step_body = _step_body
 
         # ONE jitted callable for prefill, decode, and mixed packed batches:
         # XLA compiles one program per (bucket, commit_all) — the old
@@ -612,6 +610,39 @@ class ServingEngine:
                 jnp.full((b, t), -1, jnp.int32), self.states,
                 jnp.ones((b,), bool), jnp.zeros((b,), jnp.int32), True,
                 min(self._spec_k + 1, t))
+
+    def _init_states(self):
+        """Fresh KV/recurrent states for every lane (empty caches)."""
+        paged = (dict(paged_pages=self.pool.n, page_size=self.pool.ps)
+                 if self._paged else {})
+        return init_states(self.cfg, self.scfg.batch_lanes,
+                           self.scfg.max_seq, int8_kv=self.scfg.int8_kv,
+                           window_slack=self._window_slack, **paged)
+
+    def fixed_batch_logits(self, batches, backend: str) -> list[jax.Array]:
+        """Last-row logits of the packed step on the kernel path
+        ``backend`` ("pallas" or "jnp") over ``batches``: (tokens,
+        positions) (B, T) int32 pairs run in order from fresh states, each
+        lane on pages of its own.  A reference comparison of the serving
+        step at tp=1: compiled anew per call, it leaves the engine's
+        states, pool and programs as they were."""
+        assert self.scfg.tp == 1 and self.kv_source is None
+        b = self.scfg.batch_lanes
+        states = self._init_states()
+        if self._paged:
+            mp = self.pool.mp
+            assert self.pool.n > b * mp, "pool too small for one page set"
+            states = _with_page_table(states, jnp.asarray(
+                1 + np.arange(b * mp).reshape(b, mp), jnp.int32))
+        out = []
+        with ops.use_backend(backend):   # read while tracing, just below
+            step = jax.jit(self._step_body, static_argnums=(6, 7))
+            for tokens, positions in batches:
+                last = jnp.full((b,), tokens.shape[1] - 1, jnp.int32)
+                lg, _, states = step(self.params, tokens, positions, states,
+                                     jnp.ones((b,), bool), last, True, 1)
+                out.append(lg)
+        return out
 
     def step_hlo(self, t: int) -> str:
         """Compiled HLO text of the all-lanes packed step at bucket ``t``

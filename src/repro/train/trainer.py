@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ..dist.compression import compress_grads, decompress_grads, init_error_state
+from ..kernels import ops
 from ..models import ArchConfig, lm_loss
 from ..models.moe import moe_aux_loss
 from .optimizer import AdamWConfig, OptState, adamw_update, init_opt_state
@@ -40,8 +41,11 @@ class TrainConfig:
 
 def make_loss_fn(cfg: ArchConfig, train_cfg: TrainConfig) -> Callable:
     def loss_fn(params, batch):
-        loss = lm_loss(params, cfg, batch["tokens"], batch["labels"],
-                       kv_source=batch.get("kv_source"))
+        # no Pallas kernel defines a VJP: the differentiated forward traces
+        # the jnp reference path on every platform
+        with ops.use_backend("jnp"):
+            loss = lm_loss(params, cfg, batch["tokens"], batch["labels"],
+                           kv_source=batch.get("kv_source"))
         if cfg.n_experts and train_cfg.aux_loss_weight:
             # router balance on the first-layer activations proxy: cheap and
             # effective for synthetic-data runs; production would thread the

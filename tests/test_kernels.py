@@ -3,6 +3,8 @@
 Integer kernels must be BIT-EXACT against their jnp oracle; the float flash
 attention matches to fp32 tolerance.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -246,19 +248,30 @@ class TestInt8AttentionPVDequant:
         return q, k, v, jnp.asarray(vs, jnp.float32), sc, s_score
 
     def test_bit_match_vs_composition_oracle_single_block(self, rng):
-        """One block (bq == S, bk == Skv): the fused PV-dequant pass is
-        BIT-IDENTICAL to the jnp composition oracle (same f32 sums).  The
-        query block spans S too: XLA:CPU's f32 dot picks its summation
-        order by operand shape, so a (S/2, Skv) x (Skv, D) product need
-        not match the same rows of an (S, Skv) x (Skv, D) one."""
+        """One KV block (bk == Skv), two query blocks: the fused PV-dequant
+        pass is BIT-IDENTICAL to the jnp composition oracle contracting the
+        same (bq, Skv) x (Skv, D) products (same f32 sums)."""
         from repro.kernels.int8_flash_attention import int8_flash_attention
         q, k, v, vs, _, s_score = self._quant(rng, 2, 4, 2, 64, 32)
         got = int8_flash_attention(q, k, v, s_score, causal=True,
-                                   v_scale=vs, bq=64, bk=64, interpret=True)
+                                   v_scale=vs, bq=32, bk=64, interpret=True)
         want = ref.int8_flash_attention_ref(q, k, v, s_score, causal=True,
-                                            v_scale=vs)
+                                            v_scale=vs, bq=32)
         assert got.dtype == jnp.float32
         assert (np.asarray(got) == np.asarray(want)).all()
+
+    def test_oracle_pv_rows_depend_on_product_rows(self, rng):
+        """Why the oracle takes the kernel's ``bq``: on XLA:CPU the same
+        query rows sum in another order when 32 of them share a product
+        than when all 64 do — last-ulp differences, nothing more.  One
+        query block (bq == S) is the unblocked contraction exactly."""
+        q, k, v, vs, _, s_score = self._quant(rng, 2, 4, 2, 64, 32)
+        orc = functools.partial(ref.int8_flash_attention_ref, q, k, v,
+                                s_score, causal=True, v_scale=vs)
+        whole, halves = np.asarray(orc()), np.asarray(orc(bq=32))
+        assert (np.asarray(orc(bq=64)) == whole).all()
+        assert (halves != whole).any()
+        np.testing.assert_allclose(halves, whole, rtol=1e-5, atol=1e-6)
 
     @pytest.mark.parametrize("s,d,hq,hkv", [(64, 32, 4, 2), (128, 64, 4, 4),
                                             (64, 32, 6, 3)])

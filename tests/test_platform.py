@@ -33,7 +33,9 @@ def platform_default():
 
 
 @pytest.fixture
-def fake_tpu(monkeypatch, platform_default):
+def fake_tpu(platform_default, monkeypatch):
+    # set up after platform_default, so the real on_tpu is back before
+    # platform_default restores what earlier tests left (e.g. interpret on)
     monkeypatch.setattr(common, "on_tpu", lambda: True)
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
 
@@ -75,6 +77,37 @@ def test_engine_step_refuses_reference_path_on_tpu(fake_tpu):
     ops.set_backend("jnp")
     with pytest.raises(RuntimeError, match="jnp reference"):
         eng._step_fn(*eng._all_pad_args(1))
+
+
+def test_train_step_traces_reference_path_on_tpu(fake_tpu):
+    """No kernel defines a VJP: under the TPU default (compiled Pallas) a
+    train step still differentiates, through the jnp path, and leaves the
+    process default as it found it."""
+    import numpy as np
+    from repro.configs import get_config
+    from repro.models import init_params
+    from repro.train import TrainConfig, init_opt_state, make_train_step
+    cfg = get_config("starcoder2-3b", reduced=True)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.asarray(np.random.default_rng(0).integers(
+        2, cfg.vocab_size, (2, 17)), jnp.int32)
+    batch = {"tokens": tokens[:, :-1], "labels": tokens[:, 1:]}
+    step = jax.jit(make_train_step(cfg, TrainConfig()))
+    params2, _, _, metrics = step(params, init_opt_state(params), None,
+                                  batch)
+    assert np.isfinite(float(metrics["loss"]))
+    moved = jax.tree.map(lambda a, b: bool((a != b).any()), params, params2)
+    assert any(jax.tree.leaves(moved))
+    assert ops.backend() == "pallas"
+
+
+def test_use_backend_scopes_and_restores(platform_default):
+    with ops.use_backend("pallas"):
+        assert ops.backend() == "pallas"
+        with ops.use_backend("jnp"):
+            assert ops.backend() == "jnp"
+        assert ops.backend() == "pallas"
+    assert ops.backend() == "jnp"
 
 
 def test_set_hooks_return_previous_and_validate(platform_default):
