@@ -1,0 +1,12 @@
+"""Sum of the roofline-least times over the sum of device times of the
+W8A8 GEMM kernels (plain and dual-gated) in the traced stretch, in
+percent.  Each call's least time is max(ops / int8 peak, bytes / HBM
+bandwidth), counted from the call's shapes in its HLO line: every
+operand and the result, wherever XLA placed them (bench/devtrace.py)."""
+
+
+def read(run):
+    k = (run.trace or {}).get("kernels", {}).get("gemm")
+    if not k or k["s"] <= 0 or k["min_s"] <= 0:
+        return None
+    return 100.0 * k["min_s"] / k["s"]

@@ -1,0 +1,65 @@
+"""Chip peaks and the operations and bytes a computation needs.
+
+The peaks are the published ones, keyed by ``device_kind`` as JAX reports
+it; a device that is not in the table is an error, never a default.
+The counting functions take shapes only, so the benchmark, not the
+program, decides what a kernel or a step had to do.
+"""
+from __future__ import annotations
+
+# Google Cloud documentation, "TPU v5e" (system architecture page):
+# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2 at 819 GB/s per chip.
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them to bench/roofline.py "
+                       f"with their source") from None
+
+
+def min_seconds(ops: float, nbytes: float, pk: dict,
+                ops_peak: str = "int8_ops") -> float:
+    """The least time the chip could take: the larger of operations over
+    peak and bytes over HBM bandwidth."""
+    return max(ops / pk[ops_peak], nbytes / pk["hbm_bytes_per_s"])
+
+
+def decode_attn_cost(contexts, q_heads: int, kv_heads: int,
+                     head_dim: int) -> tuple[float, float]:
+    """Single-query attention over int8 K/V with one f32 scale per token
+    and head, and one int32 position per token: for each lane, its context
+    length.  Query and output in bf16.  (ops, bytes) summed over lanes."""
+    ops = nbytes = 0.0
+    for c in contexts:
+        ops += 4.0 * c * head_dim * q_heads
+        nbytes += c * (kv_heads * (2 * head_dim + 2 * 4) + 4)
+        nbytes += 2 * 2 * q_heads * head_dim
+    return ops, nbytes
+
+
+def layer_ops_per_token(m: dict) -> float:
+    """Matmul operations of one token through every layer's projections,
+    without attention's score and value products and without the head."""
+    d, hd = m["d"], m["head_dim"]
+    q, kv = m["heads"] * hd, m["kv_heads"] * hd
+    per_layer = d * q + 2 * d * kv + q * d
+    per_layer += (3 if m["gated"] else 2) * d * m["ff"]
+    return 2.0 * m["layers"] * per_layer
+
+
+def head_ops(m: dict) -> float:
+    """The head's operations for one token whose next token is sampled."""
+    return 2.0 * m["d"] * m["vocab"]
+
+
+def attention_ops(context: int, m: dict) -> float:
+    """Score and value products of one query at ``context`` keys, all
+    layers."""
+    return 4.0 * context * m["head_dim"] * m["heads"] * m["layers"]
