@@ -1,4 +1,4 @@
-"""Launchers: production mesh, dry-run, roofline, train, serve.
+"""Launchers: production mesh, dry-run, train, serve.
 
 NOTE: importing ``dryrun`` sets XLA_FLAGS for 512 placeholder devices — only
 do that in dedicated dry-run processes, never from tests or benchmarks.

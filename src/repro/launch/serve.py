@@ -202,8 +202,10 @@ def main(argv: list[str] | None = None) -> None:
           f"mode={engine.mode}, paged={engine.paged}, "
           f"buckets={engine.chunk_buckets})")
     print(engine.stats_summary())
+    m = engine.serving_metrics()
+    print(f"queue wait p50/p99 = {m['queue_wait_p50_ms']}/"
+          f"{m['queue_wait_p99_ms']} ms (TTFT = queue wait + prefill)")
     if args.stream_gap_ms > 0:
-        m = engine.serving_metrics()
         print(f"ttft p50/p99 = {m['ttft_p50_ms']}/{m['ttft_p99_ms']} ms, "
               f"tpot p50/p99 = {m['tpot_p50_ms']}/{m['tpot_p99_ms']} ms, "
               f"queue_peak={m['queue_peak']} preempt={m['preemptions']} "

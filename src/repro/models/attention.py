@@ -423,33 +423,34 @@ def attention(
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     cross = kv_source is not None or cross_kv is not None
 
-    q = apply_linear(x, params["wq"], mode, params.get("bq"),
-                     use_hint=(None, "tp"))
-    # head counts derive from the PROJECTED widths, not cfg: inside the
-    # serving TP shard_map (dist/tp.py) wq/wk/wv are column-sharded and
-    # each shard carries n_heads/tp whole heads — cfg would over-reshape
-    q = q.reshape(b, t, q.shape[-1] // hd, hd)
-    if cross_kv is not None:
-        # static cross KV, computed once (precompute_cross_states): the
-        # per-decode-step recompute was 87% of vision-90b decode FLOPs
-        k = cross_kv[0].astype(x.dtype)
-        v = cross_kv[1].astype(x.dtype)
-    else:
-        src = kv_source if cross else x
-        k = apply_linear(src, params["wk"], mode, params.get("bk"),
+    with jax.named_scope("attn.qkv"):
+        q = apply_linear(x, params["wq"], mode, params.get("bq"),
                          use_hint=(None, "tp"))
-        v = apply_linear(src, params["wv"], mode, params.get("bv"),
-                         use_hint=(None, "tp"))
-        k = k.reshape(b, src.shape[1], k.shape[-1] // hd, hd)
-        v = v.reshape(b, src.shape[1], v.shape[-1] // hd, hd)
-    # inside the TP region heads take the model axis (seq gathers back)
-    q = shard_hint(q, "dp", None, "tp", None)
-    k = shard_hint(k, "dp", None, "tp", None)
+        # head counts derive from the PROJECTED widths, not cfg: inside the
+        # serving TP shard_map (dist/tp.py) wq/wk/wv are column-sharded and
+        # each shard carries n_heads/tp whole heads — cfg would over-reshape
+        q = q.reshape(b, t, q.shape[-1] // hd, hd)
+        if cross_kv is not None:
+            # static cross KV, computed once (precompute_cross_states): the
+            # per-decode-step recompute was 87% of vision-90b decode FLOPs
+            k = cross_kv[0].astype(x.dtype)
+            v = cross_kv[1].astype(x.dtype)
+        else:
+            src = kv_source if cross else x
+            k = apply_linear(src, params["wk"], mode, params.get("bk"),
+                             use_hint=(None, "tp"))
+            v = apply_linear(src, params["wv"], mode, params.get("bv"),
+                             use_hint=(None, "tp"))
+            k = k.reshape(b, src.shape[1], k.shape[-1] // hd, hd)
+            v = v.reshape(b, src.shape[1], v.shape[-1] // hd, hd)
+        # inside the TP region heads take the model axis (seq gathers back)
+        q = shard_hint(q, "dp", None, "tp", None)
+        k = shard_hint(k, "dp", None, "tp", None)
 
-    if not cross:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        src_pos = positions
-        k = apply_rope(k, src_pos, cfg.rope_theta)
+        if not cross:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            src_pos = positions
+            k = apply_rope(k, src_pos, cfg.rope_theta)
 
     scale = 1.0 / math.sqrt(hd)
     dtype = x.dtype
@@ -464,26 +465,33 @@ def attention(
         # the pallas backend, int8 pages) or the XLA gather-then-attend
         # view — the same _sdpa the dense cache path runs, over a view
         # that is element-identical to the dense cache (docs/serving.md)
-        cache = _write_paged(cache, k, v, positions)
+        with jax.named_scope("attn.kv_write"):
+            cache = _write_paged(cache, k, v, positions)
         ps = cache["ppos"].shape[1]
         if "pks" in cache and t == 1 and ops.backend() == "pallas":
-            out = ops.paged_attention_decode(
-                q[:, 0], cache["pk"], cache["pks"], cache["pv"],
-                cache["pvs"], cache["ppos"], cache["pt"], positions[:, 0],
-                scale=scale, window=window)[:, None].astype(dtype)
+            # the scope holds the kernel and the preparation of its
+            # operands
+            with jax.named_scope("attn.paged_decode"):
+                out = ops.paged_attention_decode(
+                    q[:, 0], cache["pk"], cache["pks"], cache["pv"],
+                    cache["pvs"], cache["ppos"], cache["pt"],
+                    positions[:, 0], scale=scale,
+                    window=window)[:, None].astype(dtype)
         else:
-            kc, vc, kpos = _read_paged(cache, dtype)
-            ctx = tp_serving_ctx()
-            bq, _ = autotune.paged_blocks(t, ps, kc.shape[1], hd,
-                                          arch=cfg.name,
-                                          backend=ops.backend(),
-                                          hkv=kc.shape[2],
-                                          tp=ctx.size if ctx else 1)
-            out = _sdpa(q, kc, vc, positions, kpos, scale, dtype,
-                        causal=True, window=window, valid=kpos >= 0,
-                        chunk=max(bq, 1))
+            with jax.named_scope("attn.gather"):
+                kc, vc, kpos = _read_paged(cache, dtype)
+                ctx = tp_serving_ctx()
+                bq, _ = autotune.paged_blocks(t, ps, kc.shape[1], hd,
+                                              arch=cfg.name,
+                                              backend=ops.backend(),
+                                              hkv=kc.shape[2],
+                                              tp=ctx.size if ctx else 1)
+                out = _sdpa(q, kc, vc, positions, kpos, scale, dtype,
+                            causal=True, window=window, valid=kpos >= 0,
+                            chunk=max(bq, 1))
     elif cache is not None:
-        cache = _write_cache(cache, k, v, positions)
+        with jax.named_scope("attn.kv_write"):
+            cache = _write_cache(cache, k, v, positions)
         if "k_s" in cache and t == 1 and ops.backend() == "pallas":
             # serving hot path: fused int8-KV decode kernel (one int8 pass
             # over the cache, in-register dequant — §Perf cell C)
@@ -525,8 +533,9 @@ def attention(
     # head-sharded, wo is replicated, and dist/tp.py rebuilds full rows
     # (barrier all-gather, or the all-to-all token split whose row GEMM
     # consumes each shard's slice as it arrives) before the epilogue.
-    out = tp_out_projection(
-        out, residual,
-        lambda h, res: apply_linear(h, params["wo"], mode,
-                                    use_hint=("tp", None), residual=res))
+    with jax.named_scope("attn.out"):
+        out = tp_out_projection(
+            out, residual,
+            lambda h, res: apply_linear(h, params["wo"], mode,
+                                        use_hint=("tp", None), residual=res))
     return shard_hint(out, "dp", "sp", None), cache

@@ -158,7 +158,8 @@ def block_forward(
         # (sequence parallel, dist/tp.py): norms run on local rows and
         # tp_row_unshard gathers full rows for the QKV / MLP-in GEMMs
         # (identity everywhere else)
-        h = apply_norm(x, params["norm1"], cfg, mode)
+        with jax.named_scope("norm"):
+            h = apply_norm(x, params["norm1"], cfg, mode)
         # SP->TP boundary: gather the bf16 norm output (not the f32 norm
         # intermediate GSPMD would otherwise pick — 2x ICI bytes)
         h = shard_hint(h, "dp", None, None)
@@ -169,13 +170,15 @@ def block_forward(
                           window=window, residual=x)
         if state is not None:
             new_state = dict(state, kv=kv)
-        h = apply_norm(x, params["norm2"], cfg, mode)
+        with jax.named_scope("norm"):
+            h = apply_norm(x, params["norm2"], cfg, mode)
         h = shard_hint(h, "dp", None, None)
         h = tp_row_unshard(h, *positions.shape)
-        if kind in ("moe", "moe_swa"):
-            x = x + moe(params["moe"], h, cfg, mode)
-        else:
-            x = x + mlp(params["mlp"], h, cfg, mode)
+        with jax.named_scope("mlp"):
+            if kind in ("moe", "moe_swa"):
+                x = x + moe(params["moe"], h, cfg, mode)
+            else:
+                x = x + mlp(params["mlp"], h, cfg, mode)
         return x, new_state
     if kind == "xattn":
         ckv = None if state is None else (state["xk"], state["xv"])

@@ -171,7 +171,8 @@ def forward(
     if embeddings is not None:
         x = embeddings.astype(mode.compute_dtype)
     else:
-        x = embed_lookup(tokens, params["embed"], mode.compute_dtype)
+        with jax.named_scope("embed"):
+            x = embed_lookup(tokens, params["embed"], mode.compute_dtype)
     b, t = x.shape[:2]
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
@@ -198,22 +199,25 @@ def forward(
         body, (x, positions),
         (xs_params, xs_states if xs_states is not None else
          [None] * cfg.period))
-    x = apply_norm(x, params["final_norm"], cfg, mode)
+    with jax.named_scope("norm"):
+        x = apply_norm(x, params["final_norm"], cfg, mode)
     x = tp_row_unshard(x, b, t)
     if not logits:
         return x, out_states
-    unembed = params.get("unembed")
-    if unembed is None:
-        # tied head: make a vocab-sharded view first (the table itself is
-        # d_model-sharded for the gather; without the reshard, the head's
-        # grads materialize the full vocab in f32 on every device)
-        unembed = shard_hint(params["embed"], "tp", None).T
-    from .layers import apply_linear
-    lg = apply_linear(x, unembed, ExecMode(cfg.precision, F32))
-    if cfg.padded_vocab != cfg.vocab_size:
-        pad_mask = jnp.arange(cfg.padded_vocab) >= cfg.vocab_size
-        lg = jnp.where(pad_mask, -1e9, lg)
-    lg = shard_hint(lg, "dp", None, "tp")
+    with jax.named_scope("head"):
+        unembed = params.get("unembed")
+        if unembed is None:
+            # tied head: make a vocab-sharded view first (the table itself
+            # is d_model-sharded for the gather; without the reshard, the
+            # head's grads materialize the full vocab in f32 on every
+            # device)
+            unembed = shard_hint(params["embed"], "tp", None).T
+        from .layers import apply_linear
+        lg = apply_linear(x, unembed, ExecMode(cfg.precision, F32))
+        if cfg.padded_vocab != cfg.vocab_size:
+            pad_mask = jnp.arange(cfg.padded_vocab) >= cfg.vocab_size
+            lg = jnp.where(pad_mask, -1e9, lg)
+        lg = shard_hint(lg, "dp", None, "tp")
     return lg, out_states
 
 

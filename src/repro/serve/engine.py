@@ -113,6 +113,7 @@ import numpy as np
 from ..kernels import ops
 from ..models import ArchConfig, forward, init_states, precompute_cross_states
 from ..models.attention import gather_pages, rollback_cache, scatter_pages
+from . import tracing
 from .draft import ngram_propose
 from .kv_pool import PagedKVPool, PoolExhaustedError
 from .queue import AdmissionQueue, QueueFullError, percentile
@@ -257,11 +258,12 @@ def _with_page_table(states, pt):
 
 def _sample(logits, temperature: float, keys):
     """Per-lane sampling: ``keys`` (B, 2) uint32, one PRNG stream per lane."""
-    if temperature <= 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jax.vmap(
-        lambda k, l: jax.random.categorical(k, l / temperature)
-    )(keys, logits).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        if temperature <= 0.0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return jax.vmap(
+            lambda k, l: jax.random.categorical(k, l / temperature)
+        )(keys, logits).astype(jnp.int32)
 
 
 def _pow2_bucket(n: int) -> int:
@@ -335,10 +337,11 @@ class ServingEngine:
             # drafted position; verify_rows == 1 is exactly the old
             # last-row gather).  Indices clip at row 0 — lanes with spans
             # shorter than verify_rows ignore the duplicate leading rows.
-            idx = jnp.maximum(
-                last_idx[:, None] - jnp.arange(verify_rows - 1, -1, -1), 0)
-            lg = jnp.take_along_axis(logits, idx[:, :, None], axis=1)
-            greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)   # (B, R)
+            with jax.named_scope("sample"):
+                idx = jnp.maximum(last_idx[:, None]
+                                  - jnp.arange(verify_rows - 1, -1, -1), 0)
+                lg = jnp.take_along_axis(logits, idx[:, :, None], axis=1)
+                greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)  # (B, R)
             if commit_all:  # static: every lane participated, skip select
                 return lg[:, -1], greedy, new_states
             return (lg[:, -1], greedy,
@@ -356,8 +359,10 @@ class ServingEngine:
         # static: the all-lanes steady state skips the full-tree lane
         # select (pure extra cache traffic there).  verify_rows is static
         # too but adds no programs: it is a fixed function of the bucket
-        # (min(spec_k + 1, bucket)).
-        self._step_fn = jax.jit(_packed_masked, static_argnums=(6, 7))
+        # (min(spec_k + 1, bucket)).  _step_jit stays the jitted callable
+        # when a caller wraps _step_fn: its cache size counts compiles.
+        self._step_fn = self._step_jit = jax.jit(_packed_masked,
+                                                 static_argnums=(6, 7))
         self.tp_mesh = None
         if serve_cfg.tp_overlap not in ("auto", "overlap", "barrier"):
             # validated even at tp=1: a typo'd boundary choice must not
@@ -418,6 +423,7 @@ class ServingEngine:
         self._clock = time.monotonic
         self.stats: dict[str, Any] = {}
         self.reset_stats()
+        self._forwards: list[tuple[int, int]] = []   # this step's (t, lanes)
 
     def _init_tp(self, packed_masked) -> None:
         """Build the tensor-parallel packed step: shard params (column-
@@ -474,7 +480,8 @@ class ServingEngine:
                 out_specs=(P(), P(), sspecs), check_vma=False)
             return f(params, tokens, positions, states, lane_mask, last_idx)
 
-        self._step_fn = jax.jit(_sharded, static_argnums=(6, 7))
+        self._step_fn = self._step_jit = jax.jit(_sharded,
+                                                 static_argnums=(6, 7))
 
     def _resolve_mode(self) -> str:
         """'packed' | 'chunked' | 'tokenwise' (recurrent archs: tokenwise —
@@ -541,24 +548,31 @@ class ServingEngine:
         (an evicted page can be re-allocated as a COW target inside one
         batch), coalescing runs of consecutive clears into one masked
         reset."""
-        pending: list[int] = []
+        if not actions:
+            return
+        with tracing.span(
+                "engine.pool",
+                clears=lambda: sum(a[0] == "clear" for a in actions),
+                copies=lambda: sum(a[0] != "clear" for a in actions)):
+            pending: list[int] = []
 
-        def flush():
-            if pending:
-                mask = np.zeros(self.pool.n, bool)
-                mask[pending] = True
-                self.states = self._clear_fn(self.states, jnp.asarray(mask))
-                pending.clear()
+            def flush():
+                if pending:
+                    mask = np.zeros(self.pool.n, bool)
+                    mask[pending] = True
+                    self.states = self._clear_fn(self.states,
+                                                 jnp.asarray(mask))
+                    pending.clear()
 
-        for act in actions:
-            if act[0] == "clear":
-                pending.append(act[1])
-                continue
+            for act in actions:
+                if act[0] == "clear":
+                    pending.append(act[1])
+                    continue
+                flush()
+                _, src, dst, keep = act
+                self.states = self._copy_fn(self.states, np.int32(src),
+                                            np.int32(dst), np.int32(keep))
             flush()
-            _, src, dst, keep = act
-            self.states = self._copy_fn(self.states, np.int32(src),
-                                        np.int32(dst), np.int32(keep))
-        flush()
 
     @property
     def chunk_buckets(self) -> tuple[int, ...]:
@@ -674,6 +688,11 @@ class ServingEngine:
             # spec_throttled counts proposals halved under pool pressure
             "spec_drafted": 0, "spec_accepted": 0, "spec_steps": 0,
             "spec_throttled": 0,
+            # admission time - submission time of each admitted request
+            "queue_wait_ms": [],
+            # packed-step compiles or compile-cache loads (after warmup()
+            # resets the stats: none expected)
+            "compiles": 0,
         }
         if self._paged:
             # prefix-hit / COW / eviction counters live in pool.stats (one
@@ -698,36 +717,37 @@ class ServingEngine:
         shape/PRNG failure mid-step.  A full bounded queue
         (``ServeConfig.queue_limit``) raises ``QueueFullError``: overload
         is explicit rejection, never a silent drop."""
-        n = len(prompt)
-        if n == 0:
-            raise ValueError("empty prompt: nothing to prefill (submit at "
-                             "least one token)")
-        if max_new < 1:
-            raise ValueError(f"max_new must be >= 1, got {max_new}")
-        if n >= self.scfg.max_seq - max_new:
-            raise ValueError(
-                f"prompt of {n} tokens cannot fit max_new={max_new} within "
-                f"max_seq={self.scfg.max_seq}: need "
-                f"len(prompt) < max_seq - max_new")
-        req = {"prompt": list(prompt), "max_new": max_new,
-               "id": request_id, "generated": [],
-               "_seq": self._submitted, "priority": int(priority),
-               "ttft_slo_ms": ttft_slo_ms, "tpot_slo_ms": tpot_slo_ms,
-               "on_token": on_token, "t_submit": self._clock()}
-        try:
-            self.queue.push(req)
-        except QueueFullError:
-            self.stats["rejected"] += 1
-            raise
-        self._submitted += 1
-        self.stats["requests"] += 1
-        self.stats["queue_peak"] = max(self.stats["queue_peak"],
-                                       len(self.queue))
-        h = self.stats["prefix_len_hist"]
-        bucket = _pow2_bucket(max(len(prompt), 1))
-        h[bucket] = h.get(bucket, 0) + 1
+        with tracing.span("engine.submit", request=lambda: str(request_id)):
+            n = len(prompt)
+            if n == 0:
+                raise ValueError("empty prompt: nothing to prefill "
+                                 "(submit at least one token)")
+            if max_new < 1:
+                raise ValueError(f"max_new must be >= 1, got {max_new}")
+            if n >= self.scfg.max_seq - max_new:
+                raise ValueError(
+                    f"prompt of {n} tokens cannot fit max_new={max_new} "
+                    f"within max_seq={self.scfg.max_seq}: need "
+                    f"len(prompt) < max_seq - max_new")
+            req = {"prompt": list(prompt), "max_new": max_new,
+                   "id": request_id, "generated": [],
+                   "_seq": self._submitted, "priority": int(priority),
+                   "ttft_slo_ms": ttft_slo_ms, "tpot_slo_ms": tpot_slo_ms,
+                   "on_token": on_token, "t_submit": self._clock()}
+            try:
+                self.queue.push(req)
+            except QueueFullError:
+                self.stats["rejected"] += 1
+                raise
+            self._submitted += 1
+            self.stats["requests"] += 1
+            self.stats["queue_peak"] = max(self.stats["queue_peak"],
+                                           len(self.queue))
+            h = self.stats["prefix_len_hist"]
+            bucket = _pow2_bucket(max(len(prompt), 1))
+            h[bucket] = h.get(bucket, 0) + 1
 
-    def _admit(self) -> None:
+    def _admit(self) -> list[dict]:
         """Fill free lanes: preempted requests resume FIRST (highest
         priority, then oldest — they already paid for their progress and
         their KV sits in the host swap buffer), then the priority queue.
@@ -735,7 +755,9 @@ class ServingEngine:
         letting new work jump past a half-served request; in paged mode a
         new request is only admitted while the pool has any headroom
         (free or evictable pages) — under pressure the queue is the
-        backpressure, not the allocator."""
+        backpressure, not the allocator.  Returns the requests taken from
+        the queue."""
+        admitted: list[dict] = []
         for lane in range(self.scfg.batch_lanes):
             if self.lane_active[lane]:
                 continue
@@ -743,14 +765,18 @@ class ServingEngine:
                 req = min(self.preempted,
                           key=lambda r: (-r["priority"], r["_seq"]))
                 if not self._try_resume(lane, req):
-                    return
+                    break
                 continue
             if not self.queue:
-                return
+                break
             if (self._paged and
                     self.pool.free_pages + self.pool.evictable_pages < 2):
-                return
+                break
             req = self.queue.pop()
+            req["t_admit"] = self._clock()
+            self.stats["queue_wait_ms"].append(
+                (req["t_admit"] - req["t_submit"]) * 1e3)
+            admitted.append(req)
             if self._paged:
                 # lane isolation = page bookkeeping: the previous request's
                 # pages were freed (and cleared) at finish; here the radix
@@ -770,6 +796,7 @@ class ServingEngine:
             # samples never depend on lane count or co-resident traffic
             self.lane_keys = self.lane_keys.at[lane].set(
                 jax.random.fold_in(self.base_key, req["_seq"]))
+        return admitted
 
     # -- preemption + KV page swap ----------------------------------------
     def _gather_pages_host(self, pids: list[int]) -> list[dict]:
@@ -1031,51 +1058,79 @@ class ServingEngine:
         if self._paged:
             # back every logical page this step writes with a lane-owned
             # physical page (alloc / copy-on-write), preempting victims
-            # under memory pressure, cap windowed lanes' live pages, then
-            # ship the updated page table
-            if not self._reserve_pages(plan):
+            # under memory pressure, cap windowed lanes' live pages
+            free, pre = self.pool.free_pages, self.stats["preemptions"]
+            with tracing.span("engine.reserve") as sp:
+                ok = self._reserve_pages(plan)
+                tracing.note(
+                    sp, pages=lambda: free - self.pool.free_pages,
+                    preemptions=lambda: self.stats["preemptions"] - pre)
+            if not ok:
                 return
-            self.states = _with_page_table(self.states,
-                                           jnp.asarray(self.pool.table))
-        need = max(plan.values())
-        t = need if need == 1 else next(
-            bk for bk in self._buckets if bk >= need)
-        vr = min(self._spec_k + 1, t)         # verify rows (static per bucket)
-        tok = np.zeros((b, t), np.int32)
-        pos = np.full((b, t), -1, np.int32)   # -1 = pad: cache write dropped
-        last_idx = np.zeros(b, np.int32)
-        mask = np.zeros(b, bool)
-        key_pos = self.lane_pos.copy()
-        n_prompt = 0
-        speculating = False
-        for lane, c in plan.items():
-            req = self.lane_request[lane]
-            p0 = int(self.lane_pos[lane])
-            if req["_pending_prompt"]:
-                tok[lane, :c] = req["_pending_prompt"][:c]
-                n_prompt += c
-            else:
-                if req["generated"]:
-                    tok[lane, 0] = req["generated"][-1]
-                if c > 1:                     # speculative draft rows
-                    tok[lane, 1:c] = req["_draft"][:c - 1]
-                    speculating = True
-            pos[lane, :c] = np.arange(p0, p0 + c)
-            last_idx[lane] = c - 1
-            key_pos[lane] = p0 + c - 1        # last fed position
-            mask[lane] = True
-        # paged mode always commits the whole tree: the shared arena has no
-        # lane dimension to mask (pad writes are position-dropped, and no
-        # per-lane state leaves exist on paged-capable archs)
-        lg, greedy, self.states = self._step_fn(
-            self.params, jnp.asarray(tok), jnp.asarray(pos), self.states,
-            jnp.asarray(mask), jnp.asarray(last_idx),
-            True if self._paged else bool(mask.all()), vr)
-        nxt = np.asarray(_sample(lg, self.scfg.temperature,
-                                 self._keys_at(key_pos)))
-        greedy = np.asarray(greedy) if speculating else None
+        with tracing.span("engine.pack") as sp:
+            if self._paged:   # ship the updated page table
+                self.states = _with_page_table(self.states,
+                                               jnp.asarray(self.pool.table))
+            need = max(plan.values())
+            t = need if need == 1 else next(
+                bk for bk in self._buckets if bk >= need)
+            vr = min(self._spec_k + 1, t)     # verify rows (static per bucket)
+            tok = np.zeros((b, t), np.int32)
+            pos = np.full((b, t), -1, np.int32)  # -1 = pad: write dropped
+            last_idx = np.zeros(b, np.int32)
+            mask = np.zeros(b, bool)
+            key_pos = self.lane_pos.copy()
+            n_prompt = 0
+            speculating = False
+            for lane, c in plan.items():
+                req = self.lane_request[lane]
+                p0 = int(self.lane_pos[lane])
+                if req["_pending_prompt"]:
+                    tok[lane, :c] = req["_pending_prompt"][:c]
+                    n_prompt += c
+                else:
+                    if req["generated"]:
+                        tok[lane, 0] = req["generated"][-1]
+                    if c > 1:                 # speculative draft rows
+                        tok[lane, 1:c] = req["_draft"][:c - 1]
+                        speculating = True
+                pos[lane, :c] = np.arange(p0, p0 + c)
+                last_idx[lane] = c - 1
+                key_pos[lane] = p0 + c - 1    # last fed position
+                mask[lane] = True
+            tracing.note(sp, rows=b * t, bucket=t)
+        self._forwards.append((t, len(plan)))
+        with tracing.span("engine.forward") as sp:
+            programs = self._step_jit._cache_size()
+            # paged mode always commits the whole tree: the shared arena
+            # has no lane dimension to mask (pad writes are position-
+            # dropped, and no per-lane state leaves exist on paged-capable
+            # archs)
+            lg, greedy, self.states = self._step_fn(
+                self.params, jnp.asarray(tok), jnp.asarray(pos), self.states,
+                jnp.asarray(mask), jnp.asarray(last_idx),
+                True if self._paged else bool(mask.all()), vr)
+            nxt = _sample(lg, self.scfg.temperature, self._keys_at(key_pos))
+            compiled = int(self._step_jit._cache_size() > programs)
+            self.stats["compiles"] += compiled
+            tracing.note(sp, compiled=compiled)
+        with tracing.span("engine.wait"):
+            nxt = np.asarray(nxt)
+            greedy = np.asarray(greedy) if speculating else None
+        with tracing.span("engine.commit") as sp:
+            done = len(self.finished)
+            n_emitted = self._commit(plan, t, vr, n_prompt, nxt, greedy)
+            tracing.note(sp, tokens=n_emitted,
+                         finished=lambda: len(self.finished) - done)
+
+    def _commit(self, plan: dict[int, int], t: int, vr: int, n_prompt: int,
+                nxt: np.ndarray, greedy: np.ndarray | None) -> int:
+        """Commit one forward's sampled (and, for speculating lanes,
+        verified) tokens lane by lane; returns the tokens emitted."""
+        b = self.scfg.batch_lanes
         st = self.stats
         st["forwards"][t] = st["forwards"].get(t, 0) + 1
+        n_first = 0                           # prompts that completed
         n_decode = 0
         rollback_keep = None                  # dense rewind bounds (B,)
         for lane, c in plan.items():
@@ -1088,6 +1143,7 @@ class ServingEngine:
                     # boundary token: sampled from the last prompt logit,
                     # key folded at the last prompt position (= decode rule)
                     self._emit(req, int(nxt[lane]))
+                    n_first += 1
                     if self._paged:
                         # prompt fully in cache: register its pages in the
                         # radix index so later submissions can share them
@@ -1147,6 +1203,7 @@ class ServingEngine:
         st["decode_tokens"] += n_decode
         # rejected speculative rows count as pads: they bought no output
         st["pad_tokens"] += t * len(plan) - n_prompt - n_decode
+        return n_first + n_decode
 
     # -- scheduler --------------------------------------------------------
     def step(self) -> None:
@@ -1156,8 +1213,36 @@ class ServingEngine:
         tokens under ``token_budget`` — no prefill/decode phase split.
         Chunked mode: the PR 2 two-call schedule (prefill chunk, then
         decode) over the same program family.  Tokenwise: single-token
-        rows for every lane."""
-        self._admit()
+        rows for every lane.
+
+        While a profiler session records, each stage is a span on the
+        trace's clock (``serve/tracing.py``): ``engine.step`` holds
+        ``engine.admit``, then per forward ``engine.plan``,
+        ``engine.reserve`` (paged), ``engine.pack``, ``engine.forward``,
+        ``engine.wait`` and ``engine.commit``; ``engine.pool`` wraps the
+        page pool's device actions wherever they run."""
+        st = self.stats
+        rows0 = (st["prompt_tokens"], st["decode_tokens"], st["pad_tokens"])
+        self._forwards = []
+        with tracing.span("engine.step") as sp:
+            self._schedule()
+            tracing.note(
+                sp, step=st["steps"], mode=self._mode,
+                bucket=lambda: max((t for t, _ in self._forwards), default=0),
+                lanes=lambda: sum(n for _, n in self._forwards),
+                prompt_rows=lambda: st["prompt_tokens"] - rows0[0],
+                decode_rows=lambda: st["decode_tokens"] - rows0[1],
+                pad_rows=lambda: st["pad_tokens"] - rows0[2])
+
+    def _schedule(self) -> None:
+        resumes = self.stats["resumes"]
+        with tracing.span("engine.admit") as sp:
+            admitted = self._admit()
+            tracing.note(
+                sp, requests=lambda: " ".join(
+                    f"{r['id']}:{(r['t_admit'] - r['t_submit']) * 1e3:.3f}"
+                    for r in admitted),
+                resumes=lambda: self.stats["resumes"] - resumes)
         if not self.lane_active.any():
             return
         self.stats["steps"] += 1
@@ -1165,24 +1250,39 @@ class ServingEngine:
                  if self.lane_active[l]]
         if self._mode == "packed":
             self.stats["budget_tokens"] += self.scfg.token_budget
-            self._run_lanes(self._plan_tokens(lanes, self.scfg.token_budget))
+            self._run_lanes(self._planned(
+                lambda: self._plan_tokens(lanes, self.scfg.token_budget)))
             return
         if self._mode == "chunked":
             prefilling = [l for l in lanes
                           if self.lane_request[l]["_pending_prompt"]]
             if prefilling:
                 # budget = lanes x cap: every lane gets a full chunk share
-                self._run_lanes(self._plan_tokens(
-                    prefilling, len(prefilling) * self._buckets[-1]))
+                self._run_lanes(self._planned(lambda: self._plan_tokens(
+                    prefilling, len(prefilling) * self._buckets[-1])))
             decoding = [l for l in lanes if self.lane_active[l]
                         and l not in prefilling]
             if decoding:
                 # decode call: 1 token per lane + any speculative draft
-                self._run_lanes({l: 1 + len(self._propose(l))
-                                 for l in decoding})
+                self._run_lanes(self._planned(
+                    lambda: {l: 1 + len(self._propose(l)) for l in decoding}))
             return
         # tokenwise: prompts feed one token per call (recurrent-arch safe)
-        self._run_lanes({l: 1 for l in lanes})
+        self._run_lanes(self._planned(lambda: {l: 1 for l in lanes}))
+
+    def _planned(self, make) -> dict[int, int]:
+        """``make()``'s plan, in an ``engine.plan`` span whose ``lanes``
+        argument records, per planned lane, its first position, token
+        count and whether the tokens are prompt (``p0:count:0|1``, one
+        per lane, space-separated: the profiler separates arguments by
+        commas)."""
+        with tracing.span("engine.plan") as sp:
+            plan = make()
+            tracing.note(sp, lanes=lambda: " ".join(
+                f"{int(self.lane_pos[l])}:{int(c)}:"
+                f"{int(bool(self.lane_request[l]['_pending_prompt']))}"
+                for l, c in plan.items()))
+        return plan
 
     def run_until_drained(self, max_iters: int = 10_000) -> list[dict]:
         it = 0
@@ -1226,11 +1326,15 @@ class ServingEngine:
         return self.finished, rejected
 
     def serving_metrics(self) -> dict:
-        """TTFT/TPOT percentiles + overload counters for the current
-        stats window (see docs/serving.md for the field glossary)."""
+        """TTFT/TPOT and queue-wait percentiles + overload counters for
+        the current stats window (see docs/serving.md for the field
+        glossary).  Queue wait runs from ``submit`` to admission into a
+        lane: TTFT is queue wait plus prefill."""
         st = self.stats
         return {
             "completed": len(st["ttft_ms"]),
+            "queue_wait_p50_ms": round(percentile(st["queue_wait_ms"], 50), 3),
+            "queue_wait_p99_ms": round(percentile(st["queue_wait_ms"], 99), 3),
             "ttft_p50_ms": round(percentile(st["ttft_ms"], 50), 3),
             "ttft_p99_ms": round(percentile(st["ttft_ms"], 99), 3),
             "tpot_p50_ms": round(percentile(st["tpot_ms"], 50), 3),
@@ -1263,7 +1367,8 @@ class ServingEngine:
                 if st["budget_tokens"] else 0.0)
         share = 100.0 * st["decode_tokens"] / valid if valid else 0.0
         out = (f"mode={self._mode} requests={st['requests']} "
-               f"steps={st['steps']} prompt_tokens={st['prompt_tokens']} "
+               f"steps={st['steps']} compiles={st['compiles']} "
+               f"prompt_tokens={st['prompt_tokens']} "
                f"decode_tokens={st['decode_tokens']} (share={share:.0f}%) "
                f"row_eff={eff:.0f}% forwards[{fwd}] prefix_hist[{hist}]")
         if st["budget_tokens"]:
