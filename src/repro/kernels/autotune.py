@@ -459,6 +459,47 @@ def decode_blocks(s: int, d: int, g: int) -> int:
     return best
 
 
+PAGED_BLOCK_TOKENS = 256   # tokens a paged decode block aims for
+# its double-buffered pages: half of VMEM
+PAGED_VMEM_BUDGET = costmodel.TPU_VMEM_BYTES // 2
+
+
+def _vmem_tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, cols) minor tile as the TPU lays it out: rows
+    padded to a power of two of at least the dtype's packing (4 int8 or
+    2 bf16 rows to a 32-bit row), at most its full sublane tile, cols to
+    the lane width."""
+    pack = 4 // itemsize
+    sub = min(SUBLANE * pack, max(pack, 1 << (rows - 1).bit_length()))
+    return _round_up(rows, sub) * _round_up(cols, LANE) * itemsize
+
+
+@functools.lru_cache(maxsize=4096)
+def paged_decode_pages(ps: int, mp: int, hkv: int, d: int,
+                       int8: bool = True) -> int:
+    """Pages per block of the paged decode kernel: the largest divisor of
+    the table width ``mp`` whose block holds at most
+    ``PAGED_BLOCK_TOKENS`` tokens (or one page) and whose double-buffered
+    pages fit ``PAGED_VMEM_BUDGET``.  A page is its K and V (ps, Hkv, D),
+    their (ps, Hkv, 1) f32 scales with int8 pages, and the (8, ps) rows of
+    slot positions that hold its own, each padded as VMEM lays it out; the
+    pool's size does not enter.  The (Hq, D) query block stays resident
+    whatever the block.  Raises if one page does not fit."""
+    page = 2 * ps * _vmem_tile_bytes(hkv, d, 1 if int8 else 2)
+    if int8:
+        page += 2 * ps * _vmem_tile_bytes(hkv, 1, 4)
+    page += _vmem_tile_bytes(SUBLANE, ps, 4)
+    fits = [ppb for ppb in range(1, mp + 1)
+            if mp % ppb == 0 and (ppb == 1 or ppb * ps <= PAGED_BLOCK_TOKENS)
+            and 2 * ppb * page <= PAGED_VMEM_BUDGET]
+    if not fits:
+        raise ValueError(
+            f"one page of the paged decode kernel ({2 * page} bytes "
+            f"double-buffered at ps={ps}, Hkv={hkv}, D={d}) exceeds "
+            f"{PAGED_VMEM_BUDGET} bytes of VMEM")
+    return max(fits)
+
+
 def elementwise_blocks(m: int, n: int, dtype: str = "int32") -> tuple[int, int]:
     """(bm, bn) for 2-D elementwise kernels (GELU, requantize): tuned row
     block + one lane-width column tile (wrappers pad columns up to it)."""

@@ -62,6 +62,40 @@ class TestTileLegality:
             bk = autotune.decode_blocks(s, 64, 4)
             assert s % bk == 0
 
+    # worked by hand from the TPU's VMEM tiles (int8 rows pack 4 to a
+    # 32-bit row, f32 (Hkv, 1) scales pad to (Hkv rounded to 2..8, 128),
+    # an (8, ps) block of int32 positions is 4 KiB); budget 8 MiB
+    @pytest.mark.parametrize("ps,mp,hkv,d,int8,want", [
+        # starcoder2-3b: K+V 16 KiB, scales 32 KiB, positions 4 KiB a
+        # page; 16 pages (256 tokens) double-buffered take 1.6 MiB
+        (16, 128, 2, 128, True, 16),
+        # codeqwen1.5-7b as configured, 32 KV heads: K+V 128 KiB, scales
+        # 512 KiB a page; 6 pages would fit, 4 divides the table
+        (16, 128, 32, 128, True, 4),
+        (16, 128, 1, 128, True, 16),       # a TP shard of starcoder2-3b
+        (16, 128, 2, 128, False, 16),      # bf16 pages
+        (16, 1024, 2, 128, True, 16),      # a 16384-token table
+        (8, 4, 2, 32, True, 4),            # a table narrower than a block
+        # a 64-slot page of 32 heads: 2.5 MiB a page, one fits
+        (64, 128, 32, 128, True, 1),
+    ])
+    def test_paged_decode_pages_fit(self, ps, mp, hkv, d, int8, want):
+        """Pages per block, worked out independently of the rule's own
+        padding model for each shape."""
+        assert autotune.paged_decode_pages(ps, mp, hkv, d, int8) == want
+
+    def test_paged_decode_pages_refuses_a_page_past_the_budget(self):
+        """A page that alone overflows VMEM is an error, not a silent
+        one-page block (1024 heads: 16 MiB of scales a page)."""
+        with pytest.raises(ValueError, match="exceeds"):
+            autotune.paged_decode_pages(16, 128, 1024, 128, True)
+
+    def test_paged_decode_pages_shrink_with_heads(self):
+        """More KV heads make every page wider: fewer pages per block."""
+        few = autotune.paged_decode_pages(16, 128, 2, 128)
+        many = autotune.paged_decode_pages(16, 128, 32, 128)
+        assert few * 16 == autotune.PAGED_BLOCK_TOKENS and many < few
+
     def test_attention_pv_blocks_divide_sequence(self):
         """The PV-dequant variant's own key family (f32 accumulator +
         scale-vector streams) still returns sequence-dividing tiles."""

@@ -668,6 +668,135 @@ class TestPagedAttentionDense:
                      if np.abs(got[0, h] - base[0, h]).max() > 1e-6]
             assert moved == [2 * j, 2 * j + 1]
 
+    # -- block gather: several blocks per lane, dead slots never read -----
+
+    PS, MP, D = 8, 16, 32
+
+    @pytest.fixture
+    def pages_per_block(self, monkeypatch):
+        """Pin the kernel's pages per block (the shape rule would give these
+        small tables a single block); the jit cache is dropped on both
+        sides, since the block size is read while tracing."""
+        from repro.kernels import autotune
+        from repro.kernels import paged_attention as pa
+
+        def pin(ppb):
+            monkeypatch.setattr(autotune, "paged_decode_pages",
+                                lambda *a, **k: ppb)
+            pa.paged_decode_attention.clear_cache()
+        yield pin
+        pa.paged_decode_attention.clear_cache()
+
+    def _lanes(self, rng, qpos, hkv, int8=True, dead_page=None):
+        """An arena and page tables for lanes at positions ``qpos``: each
+        lane's live logical pages map to pages of its own, filled as
+        ``_write_paged`` fills them (slot s of logical page j holds
+        position j*ps + s up to qpos, -1 past it), with a few slots below
+        qpos cleared (-1) as COW leaves them.  Table slots past the last
+        live page name ``dead_page`` (default: the null page 0)."""
+        ps, mp = self.PS, self.MP
+        live = [q // ps + 1 if q >= 0 else 0 for q in qpos]
+        npg = 2 + sum(live)
+        pk, pks, pv, pvs = self._arena(rng, npg=npg, ps=ps, hkv=hkv,
+                                       d=self.D, int8=int8)
+        if int8:                                  # absmax / 127 scales
+            pks, pvs = pks / 127, pvs / 127
+        ppos = np.full((npg, ps), -1, np.int32)
+        pt = np.full((len(qpos), mp), 0 if dead_page is None else dead_page,
+                     np.int32)
+        nxt = 2                                   # 0: null, 1: spare
+        for lane, (q, n) in enumerate(zip(qpos, live)):
+            for j in range(n):
+                pos = j * ps + np.arange(ps)
+                ppos[nxt] = np.where(pos <= q, pos, -1)
+                pt[lane, j] = nxt
+                nxt += 1
+            if n > 2:                             # COW-cleared slots
+                ppos[pt[lane, 1], 1:3] = -1
+        return pk, pks, pv, pvs, ppos, pt
+
+    def _dense_valid(self, q, k, v, pos, qpos, window=0):
+        """Oracle over the valid slots only, so that what lies in masked
+        slots (NaN included) cannot reach it; in float64."""
+        q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+        b, hq, d = q.shape
+        hkv = k.shape[2]
+        out = np.zeros((b, hq, d), np.float64)
+        for bi in range(b):
+            valid = (pos[bi] >= 0) & (pos[bi] <= int(qpos[bi]))
+            if window:
+                valid &= pos[bi] > (int(qpos[bi]) - window)
+            if not valid.any():
+                continue
+            for h in range(hq):
+                kv_h = h // (hq // hkv)
+                logits = (k[bi, valid, kv_h] @ q[bi, h]) / np.sqrt(d)
+                p = np.exp(logits - logits.max())
+                out[bi, h] = (p / p.sum()) @ v[bi, valid, kv_h]
+        return out
+
+    # (qpos per lane, window, Hkv, G, pages per block); MP*PS = 128 slots
+    BLOCK_CASES = {
+        "several_blocks": ([100, 37, 64, -1], 0, 2, 4, 4),
+        "qpos_first_slot_of_block": ([64, 32, 96, 0], 0, 2, 4, 4),
+        "full_table": ([127, 127, 5, -1], 0, 2, 4, 4),
+        "window_drops_blocks": ([100, 127, 40, 20], 20, 2, 4, 4),
+        "hkv1_g1": ([100, 37, 64, -1], 0, 1, 1, 4),
+        "hkv2_g12": ([100, 37, 64, -1], 0, 2, 12, 2),
+        "idle_lanes": ([-1, 70, -1, -1], 0, 2, 4, 8),
+    }
+
+    @pytest.mark.parametrize("int8", [True, False])
+    @pytest.mark.parametrize("case", sorted(BLOCK_CASES))
+    def test_block_gather_matches_dense_oracle(self, rng, pages_per_block,
+                                               case, int8):
+        from repro.kernels.paged_attention import paged_decode_attention
+        qpos, window, hkv, g, ppb = self.BLOCK_CASES[case]
+        pages_per_block(ppb)
+        pk, pks, pv, pvs, ppos, pt = self._lanes(rng, qpos, hkv, int8=int8)
+        q = jnp.asarray(rng.normal(size=(len(qpos), hkv * g, self.D)),
+                        jnp.float32)
+        got = np.asarray(paged_decode_attention(
+            q, pk, pks, pv, pvs, jnp.asarray(ppos), jnp.asarray(pt),
+            jnp.asarray(qpos, jnp.int32), window=window, interpret=True),
+            np.float32)
+        want = self._dense_valid(
+            q, *self._dense_view(pk, pks, pv, pvs, ppos, pt), qpos,
+            window=window)
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+        for lane, p in enumerate(qpos):
+            if p < 0:
+                assert (got[lane] == 0).all()     # idle lane: exact zeros
+
+    @pytest.mark.parametrize("int8", [True, False])
+    def test_dead_slots_are_never_read(self, rng, pages_per_block, int8):
+        """Table slots past each lane's last live page name a page of NaN
+        K/V (and scales) whose positions lie past every qpos: the output
+        stays finite and equal to the oracle, so no such page reaches the
+        arithmetic, in a live block or a dead one."""
+        from repro.kernels.paged_attention import paged_decode_attention
+        pages_per_block(4)
+        qpos = [100, 37, 64, -1]
+        pk, pks, pv, pvs, ppos, pt = self._lanes(rng, qpos, 2, int8=int8,
+                                                 dead_page=1)
+        nan = np.nan
+        if int8:
+            pks = pks.at[1].set(nan)
+            pvs = pvs.at[1].set(nan)
+        else:
+            pk = pk.at[1].set(nan)
+            pv = pv.at[1].set(nan)
+        ppos[1] = 10 ** 6
+        q = jnp.asarray(rng.normal(size=(4, 8, self.D)), jnp.float32)
+        got = np.asarray(paged_decode_attention(
+            q, pk, pks, pv, pvs, jnp.asarray(ppos), jnp.asarray(pt),
+            jnp.asarray(qpos, jnp.int32), interpret=True), np.float32)
+        assert np.isfinite(got).all()
+        want = self._dense_valid(
+            q, *self._dense_view(pk, pks, pv, pvs, ppos, pt), qpos)
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+        assert (got[3] == 0).all()
+
     def test_model_write_then_gather_roundtrip(self, rng):
         """models/attention paged write + gathered read reproduces the
         dense cache contents slot for slot (the bit-identity substrate)."""

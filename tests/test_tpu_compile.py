@@ -14,6 +14,8 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and under pytest-xdist only
 the worker given this file may take it.  Keep these tests in this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -29,6 +31,19 @@ CQ_D, CQ_FF = 4096, 13440              # codeqwen1.5-7b
 B, HQ, HKV, D = 4, 24, 2, 128          # starcoder2-3b decode lanes/heads
 S, PAGE = 512, 16                      # cache slots, page size
 N_PAGES = (B + 2) * (S // PAGE) + 1    # the engine's auto-sized pool
+CB, CS = 8, 2048                       # the cell's lanes and max_seq
+CN_PAGES = (CB + 2) * (CS // PAGE) + 1  # its pool: 1281 pages
+LB, LS = 16, 16384                     # starcoder2-3b's published context
+LN_PAGES = (LB + 2) * (LS // PAGE) + 1  # its auto-sized pool: 18433 pages
+BIG_POOL = 30001                       # a pool sized to fill HBM
+CQ_HKV = 32                            # codeqwen1.5-7b's KV heads, G 1
+
+
+def _paged_int8(b, hq, hkv, n_pages, s):
+    return [((b, hq, D), BF16), ((n_pages, PAGE, hkv, D), I8),
+            ((n_pages, PAGE, hkv, 1), F32), ((n_pages, PAGE, hkv, D), I8),
+            ((n_pages, PAGE, hkv, 1), F32), ((n_pages, PAGE), I32),
+            ((b, s // PAGE), I32), ((b,), I32)]
 
 
 @pytest.fixture(scope="module")
@@ -105,6 +120,16 @@ CASES = {
         [((B, HQ, D), BF16), ((N_PAGES, PAGE, HKV, D), BF16),
          ((N_PAGES, PAGE, HKV, D), BF16), ((N_PAGES, PAGE), I32),
          ((B, S // PAGE), I32), ((B,), I32)]),
+    "paged_attention_decode_int8_cell": (
+        ops.paged_attention_decode, _paged_int8(CB, HQ, HKV, CN_PAGES, CS)),
+    # the kernel's VMEM must not grow with the table or the pool
+    "paged_attention_decode_int8_long_context": (
+        ops.paged_attention_decode, _paged_int8(LB, HQ, HKV, LN_PAGES, LS)),
+    "paged_attention_decode_int8_large_pool": (
+        ops.paged_attention_decode, _paged_int8(CB, HQ, HKV, BIG_POOL, CS)),
+    "paged_attention_decode_int8_codeqwen": (
+        ops.paged_attention_decode,
+        _paged_int8(CB, CQ_HKV, CQ_HKV, CN_PAGES, CS)),
     # group 128: a 512-row K block holds 4 multiplier rows (the layout case)
     "gemm_w4a8": (
         ops.gemm_w4a8,
@@ -127,3 +152,57 @@ def test_kernel_compiles_for_v5e(name, one_chip, compiled_kernels):
             for shape, dtype in sig]
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo, name
+
+
+def test_paged_decode_wrapper_adds_no_arena_copy(one_chip, compiled_kernels):
+    """The layer scan of a bucket-1 step at the cell's sizes: each layer
+    scatters its token into a layer-stacked paged arena (``_write_paged``)
+    and attends through ``ops.paged_attention_decode``.  No instruction
+    under ``jit(paged_decode_attention)`` but the kernel itself may make an
+    array the size of an arena: the wrapper hands the kernel the arenas in
+    their stored shapes (no transpose, no reshape).  Layout copies that XLA
+    files under the scatter's op_name (each f32 scale arena, lane-padded
+    for the kernel) are outside this check."""
+    from repro.models.attention import _write_paged
+
+    layers = 4
+
+    def step(states, pt, q, k, v, pos):
+        def layer(carry, st):
+            c = _write_paged(dict(st, pt=pt), k, v, pos)
+            o = ops.paged_attention_decode(q, c["pk"], c["pks"], c["pv"],
+                                           c["pvs"], c["ppos"], pt,
+                                           pos[:, 0])
+            del c["pt"]
+            return carry + o.astype(F32).sum(), c
+        return jax.lax.scan(layer, jnp.zeros((), F32), states)
+
+    arena = lambda *tail, dt: jax.ShapeDtypeStruct(
+        (layers, CN_PAGES, PAGE) + tail, dt, sharding=one_chip)
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    states = {"pk": arena(HKV, D, dt=I8), "pv": arena(HKV, D, dt=I8),
+              "pks": arena(HKV, 1, dt=F32), "pvs": arena(HKV, 1, dt=F32),
+              "ppos": arena(dt=I32)}
+    hlo = jax.jit(step).lower(
+        states, arg((CB, CS // PAGE), I32), arg((CB, HQ, D), BF16),
+        arg((CB, 1, HKV, D), BF16), arg((CB, 1, HKV, D), BF16),
+        arg((CB, 1), I32)).compile().as_text()
+    itemsize = {"s8": 1, "pred": 1, "bf16": 2, "f32": 4, "s32": 4}
+    smallest = CN_PAGES * PAGE * 4                   # the ppos arena
+    kernels, made = 0, []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]", line)
+        if not m or "jit(paged_decode_attention)" not in line:
+            continue
+        if "tpu_custom_call" in line:
+            kernels += 1
+            continue
+        dims = [int(x) for x in m.group(3).split(",") if x]
+        nbytes = itemsize.get(m.group(2), 4)
+        for x in dims:
+            nbytes *= x
+        if CN_PAGES in dims or nbytes >= smallest:
+            made.append(line.strip()[:160])
+    assert kernels >= 1
+    assert not made, made
