@@ -5,6 +5,15 @@ kernels' device time and roofline shares.
 small plain record (device ops per chip, host spans); ``reduce`` turns
 that record and the run's own bookkeeping into numbers.  The record is
 JSON-able, so a trace recorded on the chip can be kept as a test input.
+
+The kernels it costs are found by name, as the harness finds
+configurations, families, traffic mixes and metric readers:
+``kernels/<kernel>.py`` under the harness's files, named after the HLO
+instruction the kernel's custom call lowers to, gives its ``KIND`` (its
+key under ``run.trace["kernels"]``) and one of two costs:
+``cost_from_hlo(line) -> (ops, bytes, feeds)`` of one call, with the
+peak ``PEAK`` it is held to, or ``cost_from_run(run) -> (ops, bytes,
+peak_key)`` of all its calls in the traced stretch.
 """
 from __future__ import annotations
 
@@ -12,7 +21,10 @@ import glob
 import os
 import re
 
+import byname
 import roofline
+
+HERE = os.path.dirname(os.path.abspath(__file__))
 
 HOST_SPANS = ("traced", "step", "submit")
 
@@ -27,14 +39,21 @@ def options():
     return o
 
 
-def events(trace_dir: str, chips: int = 1) -> dict:
+def kernels(root: str = HERE) -> dict:
+    """``<root>/kernels/<kernel>.py`` -> its module, by kernel name."""
+    d = os.path.join(root, "kernels")
+    return {f[:-3]: byname.module(os.path.join(d, f))
+            for f in sorted(os.listdir(d)) if f.endswith(".py")}
+
+
+def events(trace_dir: str, chips: int, kern: dict) -> dict:
     """The newest trace in ``trace_dir`` as a small plain record:
     {"device": [[kind, start_ns, dur_ns, ops, bytes, feed_ns], ...] per
     chip, "host": [[name, start_ns, dur_ns], ...]}.  ``kind`` is the op's
     instruction name without its number; ``ops`` and ``bytes`` are the
-    work of a W8A8 GEMM call, counted from the HLO line the trace names
-    it by, and ``feed_ns`` the device time of the ops that placed its
-    weights in VMEM just before it (all 0 for other ops)."""
+    work of a call of a kernel of ``kern`` costed from its HLO line, and
+    ``feed_ns`` the device time of the ops that placed its weights in
+    VMEM just before it (all 0 for other ops)."""
     from jax.profiler import ProfileData
     files = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                              recursive=True), key=os.path.getmtime)
@@ -48,7 +67,7 @@ def events(trace_dir: str, chips: int = 1) -> dict:
             raw = [(int(ev.start_ns), int(ev.duration_ns), ev.name)
                    for line in plane.lines if line.name == "XLA Ops"
                    for ev in line.events]
-            dev.append(device_ops(raw, seen))
+            dev.append(device_ops(raw, kern, seen))
         elif plane.name == "/host:CPU":
             for line in plane.lines:
                 for ev in line.events:
@@ -58,20 +77,21 @@ def events(trace_dir: str, chips: int = 1) -> dict:
     return {"device": dev, "host": sorted(host, key=lambda e: e[1])}
 
 
-def device_ops(raw, seen: dict | None = None) -> list[list]:
+def device_ops(raw, kern: dict, seen: dict | None = None) -> list[list]:
     """[start_ns, dur_ns, HLO line] of one chip's ops -> the record's
-    [kind, start_ns, dur_ns, ops, bytes, feed_ns], in time order.  A GEMM
-    weight in VMEM was produced by the latest op of its name before the
-    GEMM (the one of the same program run and layer); that op's time is
-    the GEMM's ``feed_ns``.  ``seen`` caches each distinct line's parse
-    (each program's ops repeat)."""
+    [kind, start_ns, dur_ns, ops, bytes, feed_ns], in time order.  A
+    kernel's weight in VMEM was produced by the latest op of its name
+    before the call (the one of the same program run and layer); that
+    op's time is the call's ``feed_ns``.  ``seen`` caches each distinct
+    line's parse (each program's ops repeat)."""
     seen = {} if seen is None else seen
     ops, last = [], {}
     for start, dur, name in sorted(raw, key=lambda e: e[0]):
         if name not in seen:
             kind = op_kind(name)
-            cost = (gemm_cost_from_hlo(name)
-                    if KERNELS.get(kind) == "gemm" else (0, 0, ()))
+            k = kern.get(kind)
+            cost = (k.cost_from_hlo(name) if hasattr(k, "cost_from_hlo")
+                    else (0, 0, ()))
             seen[name] = (kind, instruction(name), *cost)
         kind, instr, n_ops, n_bytes, feeds = seen[name]
         feed = sum(last.get(f, 0) for f in feeds)
@@ -125,6 +145,13 @@ _SHAPE = re.compile(r"([a-z]+\d*)\[([\d,]*)\](\{[^}]*\})?(?:\s+(%[\w.\-]+))?")
 _BYTES = {"s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4, "f32": 4}
 
 
+def array_bytes(dtype: str, dims) -> int:
+    out = _BYTES[dtype]
+    for x in dims:
+        out *= x
+    return out
+
+
 def operand_shapes(text: str) -> list[tuple[str, tuple[int, ...], bool, str]]:
     """(dtype, dims, in VMEM, name) of each array type written in
     ``text``; a layout with memory space ``S(1)`` is in the core's VMEM,
@@ -133,9 +160,6 @@ def operand_shapes(text: str) -> list[tuple[str, tuple[int, ...], bool, str]]:
              name) for t, d, lay, name in _SHAPE.findall(text)]
 
 
-# kernel name (the HLO instruction the Pallas call lowers to) -> kind
-KERNELS = {"int8_gemm": "gemm", "dual_gemm_gated": "gemm",
-           "paged_decode_attention": "decode_attn"}
 # parents whose events span their children's: not leaf device work
 CONTROL = ("while", "conditional", "call")
 _NAME = re.compile(r"%?([\w.\-]+?)(?:\.\d+)? = ")
@@ -144,59 +168,32 @@ _INSTR = re.compile(r"(%[\w.\-]+) = ")
 
 def op_kind(name: str) -> str:
     """The instruction's name without its number: the trace names a device
-    op by its HLO line, ``%int8_gemm.57 = bf16[8,256]... custom-call(...)``
-    -> ``int8_gemm``."""
+    op by its HLO line, ``%fusion.57 = bf16[8,256]... fusion(...)`` ->
+    ``fusion``; a Pallas call's custom call is named after its kernel."""
     m = _NAME.match(name)
     return m.group(1) if m else name.split(" ")[0].lstrip("%")
 
 
 def instruction(name: str) -> str:
-    """The instruction's name with its number (``%int8_gemm.57``)."""
+    """The instruction's name with its number (``%fusion.57``)."""
     m = _INSTR.match(name)
     return m.group(1) if m else name
 
 
-def classify(name: str):
-    """Which of the benchmark's kernels a device op is, by the kernel name
-    its HLO instruction carries: the W8A8 GEMMs, plain and dual-gated
-    ("gemm"), and the paged int8 decode kernel ("decode_attn")."""
-    return KERNELS.get(op_kind(name))
+def classify(name: str, kern: dict):
+    """The kind of the benchmark's kernel that a device op is, by the
+    kernel name its HLO instruction carries (None for any other op)."""
+    k = kern.get(op_kind(name))
+    return k.KIND if k else None
 
 
-def gemm_cost_from_hlo(line: str) -> tuple[float, float, tuple[str, ...]]:
-    """Operations, bytes and VMEM-fed weights of one W8A8 GEMM call from
-    the HLO line the trace names it by: 2 m k n per int8 weight (k, n)
-    against the int8 activation (m, k); the bytes of the result and of
-    every operand, wherever XLA placed them; and the names of the weights
-    XLA had already placed in VMEM (``S(1)``), whose HBM bytes moved in
-    the ops that produced them, not in the kernel."""
-    head, rest = line.split(" custom-call(", 1)
-    args = rest.split("), custom_call_target", 1)[0]
-    res = operand_shapes(head)[0]
-    operands = operand_shapes(args)
-    mats = [o for o in operands if o[0] == "s8" and len(o[1]) == 2]
-    (m, k), weights = mats[0][1], mats[1:]
-    ops = 2.0 * m * k * sum(w[1][1] for w in weights)
-    nbytes = sum(_BYTES[t] * _prod(d) for t, d, _, _ in [res, *operands])
-    feeds = tuple(name for _, _, vmem, name in weights if vmem and name)
-    return ops, float(nbytes), feeds
-
-
-def _prod(dims) -> int:
-    out = 1
-    for x in dims:
-        out *= x
-    return out
-
-
-def reduce(trace_dir_or_events, run) -> dict:
-    """busy_s, window_s, the breakdown, and per-kernel device time with
-    the least time their work needs (``min_s``), over the traced stretch
-    (the host span ``traced``).  Every device op is clipped to it; an
-    engine step blocks on its sampled tokens, so the device work of the
-    steps that started in the stretch lies inside it."""
-    ev = (trace_dir_or_events if isinstance(trace_dir_or_events, dict)
-          else events(trace_dir_or_events))
+def reduce(ev: dict, run, kern: dict) -> dict:
+    """busy_s, window_s, the breakdown, and for each kind of the kernels
+    in ``kern`` their device time with the least time their work needs
+    (``min_s``), over the traced stretch (the host span ``traced``) of
+    the record ``ev``.  Every device op is clipped to it; an engine step
+    blocks on its sampled tokens, so the device work of the steps that
+    started in the stretch lies inside it."""
     host = ev["host"]
     win = [h for h in host if h[0] == "traced"]
     if win:
@@ -205,7 +202,7 @@ def reduce(trace_dir_or_events, run) -> dict:
         lo = min(e[1] for ops in ev["device"] for e in ops)
         hi = max(e[1] + e[2] for ops in ev["device"] for e in ops)
     busy, per_op, idle = [], {}, []
-    kern = {"gemm": [0.0, 0.0], "decode_attn": [0.0, 0.0]}   # [s, min_s]
+    by_kind = {k.KIND: [0.0, 0.0] for k in kern.values()}   # [s, min_s]
     pk = run.peaks if run is not None else {}
     for ops in ev["device"]:
         iv = []
@@ -215,43 +212,44 @@ def reduce(trace_dir_or_events, run) -> dict:
                 continue
             iv.append((s0, e0))
             per_op[base] = per_op.get(base, 0.0) + (e0 - s0) * 1e-9
-            k = KERNELS.get(base)
+            k = kern.get(base)
             if k:
-                kern[k][0] += (e0 - s0) * 1e-9
-            if k == "gemm" and pk:
+                by_kind[k.KIND][0] += (e0 - s0) * 1e-9
+            if hasattr(k, "cost_from_hlo") and pk:
                 # a call cut by the stretch's edge counts its share inside;
                 # the ops that streamed its weights into VMEM are its time
                 part = (e0 - s0) / d
-                kern[k][0] += feed * part * 1e-9
-                kern[k][1] += roofline.min_seconds(n_ops, n_bytes, pk) * part
+                by_kind[k.KIND][0] += feed * part * 1e-9
+                by_kind[k.KIND][1] += roofline.min_seconds(
+                    n_ops, n_bytes, pk, k.PEAK) * part
         busy.append(union_ns(iv) * 1e-9)
         for g0, g1 in gaps(iv, lo, hi):
             idle.append((host_span_at(host, (g0 + g1) // 2),
                          (g1 - g0) * 1e-9))
-    if run is not None and pk and kern["decode_attn"][0] > 0:
-        m = run.arch
-        ops_, nbytes = roofline.decode_attn_cost(
-            decode_contexts(run.traced_steps()), m["heads"], m["kv_heads"],
-            m["head_dim"])
-        kern["decode_attn"][1] = m["layers"] * roofline.min_seconds(
-            ops_, nbytes, pk, "bf16_flops")
+    for k in kern.values():
+        if hasattr(k, "cost_from_run") and pk and by_kind[k.KIND][0] > 0:
+            ops_, nbytes, peak = k.cost_from_run(run)
+            by_kind[k.KIND][1] += roofline.min_seconds(ops_, nbytes, pk,
+                                                       peak)
     n = max(len(ev["device"]), 1)
     top = sorted(per_op.items(), key=lambda kv: -kv[1])[:10]
     idle.sort(key=lambda g: -g[1])
     return {
         "busy_s": sum(busy) / n,
         "window_s": (hi - lo) * 1e-9,
-        "kernels": {k: {"s": v[0], "min_s": v[1]} for k, v in kern.items()},
+        "kernels": {k: {"s": v[0], "min_s": v[1]}
+                    for k, v in by_kind.items()},
         "breakdown": {"device_ops": [[k, v] for k, v in top],
                       "idle_gaps": [[k, v] for k, v in idle[:10]]},
     }
 
 
 def decode_contexts(steps) -> list[int]:
-    """The context of every query the paged decode kernel answered: the
-    program calls it in the steps whose rows are one token a lane (bucket
-    1), for every lane the step planned, decoding or taking its prompt's
-    last token alike; a query at position p attends p + 1 keys."""
+    """The context of every query answered by single-query decode
+    attention: the program runs it in the steps whose rows are one token
+    a lane (bucket 1), for every lane the step planned, decoding or taking
+    its prompt's last token alike; a query at position p attends p + 1
+    keys."""
     return [p0 + 1 for s in steps
             if s.spans and all(c == 1 for _, c, _ in s.spans)
             for p0, _, _ in s.spans]

@@ -1,11 +1,12 @@
-"""Plain float32 reference of the dense decoder families the benchmark runs.
+"""The ``dense`` family's plain float32 reference, and its work counts.
 
 It imports nothing of the program under test.  The weights come from
-``layer_weights`` / ``top_weights`` below, which the harness also uses to
-make the program's float weights before the program quantizes them; the
-reference makes them again from the seed, layer by layer, on the device.
+``layer_weights`` / ``top_weights`` below, which ``program.py`` beside it
+also uses to make the program's float weights before the program
+quantizes them; the reference makes them again from the seed, layer by
+layer, on the device.
 
-The layer equations follow each family's published description:
+The layer equations follow each model type's published description:
 
 * ``starcoder2`` (bigcode/starcoder2, arXiv:2402.19173): LayerNorm with
   bias, rotary positions (rotate-half), grouped-query attention, biases on
@@ -28,6 +29,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import roofline
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -238,3 +241,39 @@ def logit_gaps(seed: int, model: dict, seqs: list[tuple[list[int], list[int]]],
     gaps = np.asarray(gaps)
     return ([gaps[i, :len(s)] for i, (_, s) in enumerate(seqs)],
             float(np.mean(np.asarray(scale))))
+
+
+# ---------------------------------------------------------------------------
+# work counts: what the model requires, from its numbers alone
+# ---------------------------------------------------------------------------
+# Every layer attends over the whole context (full causal attention, as in
+# ``_layer_one`` and the program).
+
+def ops_per_token(a: dict) -> float:
+    """Matmul operations of one token through every layer's projections,
+    without attention's score and value products and without the head."""
+    d, hd = a["d"], a["head_dim"]
+    q, kv = a["heads"] * hd, a["kv_heads"] * hd
+    per_layer = d * q + 2 * d * kv + q * d
+    per_layer += (3 if a["gated"] else 2) * d * a["ff"]
+    return 2.0 * a["layers"] * per_layer
+
+
+def span_attention_ops(a: dict, p0: int, c: int) -> float:
+    """Score and value products of ``c`` queries at positions p0 ..
+    p0 + c - 1, every layer."""
+    return (4.0 * a["head_dim"] * a["heads"] * a["layers"]
+            * roofline.span_keys(p0, c))
+
+
+def decode_attn_work(a: dict, contexts) -> tuple[float, float]:
+    """(ops, bytes) of single-query attention over the int8 paged cache at
+    each of ``contexts``, summed over the layers."""
+    ops, nbytes = roofline.decode_attn_cost(contexts, a["heads"],
+                                            a["kv_heads"], a["head_dim"])
+    return a["layers"] * ops, a["layers"] * nbytes
+
+
+def head_ops(a: dict) -> float:
+    """The head's operations for one token whose next token is sampled."""
+    return 2.0 * a["d"] * a["vocab"]

@@ -2,16 +2,19 @@
 and the comparison with the plain reference that decides ``correct``.
 
 Everything a cell needs is found by name: its configuration in
-``configs/<config>.json``, its traffic in ``traffic/<traffic>.json``, and
-each metric's reader in ``metrics/<metric>.py`` (a ``read(run)`` that
-returns a number, or None where it finds nothing to read).
+``configs/<config>.json``; the model's own code in
+``families/<family>/`` (``reference.py`` and ``program.py``, the family
+the configuration names: ``model.py``); its traffic in
+``traffic/<traffic>.json``; each metric's reader in
+``metrics/<metric>.py`` (a ``read(run)`` that returns a number, or None
+where it finds nothing to read); and the cost of each kernel the trace
+reduction counts in ``kernels/<kernel>.py`` (``devtrace.py``).
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import gc
-import importlib.util
 import json
 import os
 import shutil
@@ -22,9 +25,9 @@ from typing import Any
 import jax
 import numpy as np
 
+import byname
 import devtrace
 import model
-import reference
 import roofline
 import traffic as traffic_mod
 
@@ -59,12 +62,7 @@ def metrics_for(bench: dict, cell: str, trace: bool) -> list[dict]:
 
 
 def reader(name: str, root: str = HERE):
-    path = os.path.join(root, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return byname.module(os.path.join(root, "metrics", f"{name}.py")).read
 
 
 # ---------------------------------------------------------------------------
@@ -94,7 +92,8 @@ class Run:
     """What a metric reader gets."""
     cell: str
     lanes: int
-    arch: dict                 # reference.arch of the configuration
+    arch: dict                 # family.reference.arch of the configuration
+    family: model.Family
     setup_s: float
     t_open: float
     t_close: float
@@ -156,23 +155,24 @@ def run_cell(root: str, cell_name: str, seed: int, seconds: float,
              precision: str | None = None, files: str = HERE) -> dict:
     """One run on the first chips of ``jax.devices()``; returns the result
     line's object.  ``precision`` runs the program at another precision
-    (the control); ``files`` is the directory holding configs/, traffic/
-    and metrics/; a trace of the window's last ``TRACE_S`` seconds goes to
-    ``<root>/.bench_out/trace``."""
+    (the control); ``files`` is the directory holding configs/,
+    families/, traffic/, metrics/ and kernels/; a trace of the window's
+    last ``TRACE_S`` seconds goes to ``<root>/.bench_out/trace``."""
     bench = bench or load_benchmark(root)
     cell = workload(bench, cell_name)
     conf = model.load(cell["config"], files)
+    fam = model.family(conf["family"], files)
     spec = traffic_mod.load(cell["traffic"], files)
     devices = jax.devices()[:cell["chips"]]
     kind = devices[0].device_kind
     pk = roofline.peaks(kind) if devices[0].platform == "tpu" else {}
-    arch = reference.arch(conf["model"])
+    arch = fam.reference.arch(conf["model"])
     lanes = conf["serving"]["lanes"]
 
     from repro.kernels import autotune
     log(f"tiles: {autotune.tile_source()}")
-    cfg = model.arch_config(conf, precision)
-    params = model.program_params(conf, cfg, seed)
+    cfg = fam.program.arch_config(conf, precision)
+    params = fam.program.program_params(conf, cfg, seed)
     engine = model.build_engine(conf, cfg, params, seed)
     del params
     engine.warmup()
@@ -243,7 +243,7 @@ def run_cell(root: str, cell_name: str, seed: int, seconds: float,
 
     mem_peak = max(d.memory_stats().get("peak_bytes_in_use", 0)
                    for d in devices) if devices[0].platform == "tpu" else 0
-    run = Run(cell_name, lanes, arch, setup_s, window[0], t_close,
+    run = Run(cell_name, lanes, arch, fam, setup_s, window[0], t_close,
               list(reqs.values()), steps, pk, traced)
     attempted = sum(any(run.in_window(t) for t in r.token_t)
                     for r in run.requests)
@@ -258,7 +258,9 @@ def run_cell(root: str, cell_name: str, seed: int, seconds: float,
               "count": len(devices), "memory_peak_bytes": int(mem_peak)}
     out: dict[str, Any] = {}
     if trace:
-        red = devtrace.reduce(devtrace.events(trace_dir, len(devices)), run)
+        kern = devtrace.kernels(files)
+        red = devtrace.reduce(devtrace.events(trace_dir, len(devices), kern),
+                              run, kern)
         run.trace = red
         device["busy_s"] = red["busy_s"]
         device["window_s"] = red["window_s"]
@@ -269,7 +271,7 @@ def run_cell(root: str, cell_name: str, seed: int, seconds: float,
         if v is not None:
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}
 
-    check = compare(conf, seed, finished)
+    check = compare(conf, fam, seed, finished)
     correct = all(holds(c) for c in check.values())
     for name, c in check.items():
         log(f"{name} {c['value']!r} limit "
@@ -303,15 +305,17 @@ def spread(n: int, k: int) -> np.ndarray:
     return np.unique(np.round(np.linspace(0, n - 1, min(n, k))).astype(int))
 
 
-def compare(conf: dict, seed: int, finished: list[Request]) -> dict:
-    """Served tokens against the plain reference, over a sample of up to
-    ``sample_requests`` finished requests: at ``tokens_per_request``
-    served positions of each, spread over its output, the gap by which the
-    served token's logit lies below the reference's best; their mean is
-    held to the configuration's limit, and the sample must hold at least
-    ``min_requests`` requests.  (The widest gap is logged beside it: it
-    swings with one near-tie and does not separate the program from the
-    lower-precision control; PERF.md gives both readings.)"""
+def compare(conf: dict, fam: model.Family, seed: int,
+            finished: list[Request]) -> dict:
+    """Served tokens against the family's plain reference, over a sample
+    of up to ``sample_requests`` finished requests: at
+    ``tokens_per_request`` served positions of each, spread over its
+    output, the gap by which the served token's logit lies below the
+    reference's best; their mean is held to the configuration's limit,
+    and the sample must hold at least ``min_requests`` requests.  (The
+    widest gap is logged beside it: it swings with one near-tie and does
+    not separate the program from the lower-precision control; PERF.md
+    gives both readings.)"""
     lim = conf["correct"]
     picked = sample(finished, seed, lim["sample_requests"])
     check = {"requests_checked": {"value": len(picked),
@@ -320,7 +324,7 @@ def compare(conf: dict, seed: int, finished: list[Request]) -> dict:
     if not picked:
         return check
     t0 = time.perf_counter()
-    gaps, scale = reference.logit_gaps(
+    gaps, scale = fam.reference.logit_gaps(
         seed, conf["model"], [(r.prompt, r.tokens) for r in picked])
     flat = np.concatenate([g[spread(len(g), lim["tokens_per_request"])]
                            for g in gaps])

@@ -1,8 +1,9 @@
 """Sum of the roofline-least times over the sum of device times of the
-W8A8 GEMM kernels (plain and dual-gated) in the traced stretch, in
-percent.  Each call's least time is max(ops / int8 peak, bytes / HBM
-bandwidth), counted from the call's shapes in its HLO line: every
-operand and the result, wherever XLA placed them (bench/devtrace.py)."""
+kernels of kind ``gemm`` (the W8A8 GEMMs, bench/kernels/) in the traced
+stretch, in percent.  Each call's least time is max(ops / int8 peak,
+bytes / HBM bandwidth), counted from the call's shapes in its HLO line by
+the kernel's cost file: every operand and the result, wherever XLA placed
+them."""
 
 
 def read(run):

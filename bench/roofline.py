@@ -2,8 +2,9 @@
 
 The peaks are the published ones, keyed by ``device_kind`` as JAX reports
 it; a device that is not in the table is an error, never a default.
-The counting functions take shapes only, so the benchmark, not the
-program, decides what a kernel or a step had to do.
+The counting helpers take shapes only, so the benchmark, not the
+program, decides what a kernel or a step had to do; each family's
+``reference.py`` adds up its own layers with them.
 """
 from __future__ import annotations
 
@@ -31,35 +32,26 @@ def min_seconds(ops: float, nbytes: float, pk: dict,
     return max(ops / pk[ops_peak], nbytes / pk["hbm_bytes_per_s"])
 
 
-def decode_attn_cost(contexts, q_heads: int, kv_heads: int,
-                     head_dim: int) -> tuple[float, float]:
-    """Single-query attention over int8 K/V with one f32 scale per token
-    and head, and one int32 position per token: for each lane, its context
-    length.  Query and output in bf16.  (ops, bytes) summed over lanes."""
+def decode_attn_cost(contexts, q_heads: int, kv_heads: int, head_dim: int,
+                     window: int = 0) -> tuple[float, float]:
+    """One layer's single-query attention over int8 K/V with one f32 scale
+    per token and head, and one int32 position per token: for each lane,
+    its context length, or ``window`` keys where the layer's window is
+    shorter (0: no window).  Query and output in bf16.  (ops, bytes)
+    summed over lanes."""
     ops = nbytes = 0.0
     for c in contexts:
+        c = min(c, window) if window else c
         ops += 4.0 * c * head_dim * q_heads
         nbytes += c * (kv_heads * (2 * head_dim + 2 * 4) + 4)
         nbytes += 2 * 2 * q_heads * head_dim
     return ops, nbytes
 
 
-def layer_ops_per_token(m: dict) -> float:
-    """Matmul operations of one token through every layer's projections,
-    without attention's score and value products and without the head."""
-    d, hd = m["d"], m["head_dim"]
-    q, kv = m["heads"] * hd, m["kv_heads"] * hd
-    per_layer = d * q + 2 * d * kv + q * d
-    per_layer += (3 if m["gated"] else 2) * d * m["ff"]
-    return 2.0 * m["layers"] * per_layer
-
-
-def head_ops(m: dict) -> float:
-    """The head's operations for one token whose next token is sampled."""
-    return 2.0 * m["d"] * m["vocab"]
-
-
-def attention_ops(context: int, m: dict) -> float:
-    """Score and value products of one query at ``context`` keys, all
-    layers."""
-    return 4.0 * context * m["head_dim"] * m["heads"] * m["layers"]
+def span_keys(p0: int, c: int, window: int = 0) -> float:
+    """Keys that ``c`` causal queries at positions p0 .. p0 + c - 1 attend
+    together in one layer: p + 1 at position p, or ``window`` where the
+    layer's window is shorter (0: no window)."""
+    # the first m queries' contexts fit inside the window
+    m = c if not window else max(0, min(c, window - p0))
+    return m * p0 + m * (m + 1) / 2 + (c - m) * window

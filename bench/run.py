@@ -3,8 +3,10 @@
     python bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 run from the root of a checkout.  The cell is an entry of BENCHMARK.json's
-``workloads``; its configuration, traffic mix and metrics are files under
-``bench/`` found by name.  The last line of standard output is one JSON
+``workloads``; its configuration, the model family that configuration
+names, its traffic mix, its metrics and the costs of the kernels the
+trace reduction counts are files under ``bench/`` found by name
+(``bench/harness.py``).  The last line of standard output is one JSON
 object: ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``
 (with ``--trace 1`` also ``breakdown``) and last ``check``, each number
 compared with the plain reference beside its limit.  Without an

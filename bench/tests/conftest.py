@@ -1,7 +1,7 @@
 """The benchmark's own tests, on the CPU at tiny widths: they put
 ``bench/`` and the program's sources on the path, and build a directory of
-configurations, traffic mixes and metric readers for the harness to find
-by name."""
+configurations, families, traffic mixes, metric readers and kernel costs
+for the harness to find by name."""
 import os
 import shutil
 import sys
@@ -18,9 +18,12 @@ for p in (os.path.join(ROOT, "src"), BENCH):
 
 @pytest.fixture
 def files(tmp_path):
-    """configs/, traffic/ and metrics/ as the harness finds them: the
-    committed metric readers, the tiny test configurations and mixes."""
-    shutil.copytree(os.path.join(BENCH, "metrics"), tmp_path / "metrics")
+    """configs/, families/, traffic/, metrics/ and kernels/ as the harness
+    finds them: the committed families, metric readers and kernel costs,
+    the tiny test configurations and mixes."""
+    for sub in ("families", "metrics", "kernels"):
+        shutil.copytree(os.path.join(BENCH, sub), tmp_path / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     for sub in ("configs", "traffic"):
         shutil.copytree(os.path.join(TESTS, "data", sub), tmp_path / sub)
     return tmp_path

@@ -2,10 +2,13 @@
 a chip: the timed path compared with the plain reference decides
 ``correct``; the lower-precision control and the faults a serving cell can
 have (a step that returns its state unchanged, a token altered where it
-is produced) come out not correct; a new traffic mix and a new metric are
-found by name from their files alone."""
+is produced) come out not correct; a new traffic mix, a new metric, a new
+model family and a new kernel cost are found by name from their files
+alone."""
+import gzip
 import json
 import os
+import shutil
 import subprocess
 import sys
 import time
@@ -14,7 +17,8 @@ import pytest
 
 import harness
 import model
-from conftest import BENCH, ROOT, tiny_bench
+import roofline
+from conftest import BENCH, ROOT, TESTS, tiny_bench
 
 SEED = 2 ** 31 + 4242
 CONFIG = "tiny-starcoder2"
@@ -102,6 +106,94 @@ def test_new_mix_and_metric_found_by_name(files):
                            files=str(files))
     assert out["correct"]
     assert out["metrics"]["requests_seen"]["value"] > 0
+
+
+# appended to a copy of the dense family's reference.py: each work count
+# records its calls
+RECORDER = """
+
+CALLS = []
+
+
+def _recorded(f):
+    def recorded(*args):
+        CALLS.append(f.__name__)
+        return f(*args)
+    return recorded
+
+
+for _name in ("ops_per_token", "span_attention_ops", "decode_attn_work",
+              "head_ops"):
+    globals()[_name] = _recorded(globals()[_name])
+"""
+
+KERNEL = '''"""The integer LayerNorm, at a made-up cost."""
+KIND = "norm"
+
+
+def cost_from_run(run):
+    return 1e9, 2e9, "bf16_flops"
+'''
+
+READERS = {
+    "model_ops": """def read(run):
+    ref, a = run.family.reference, run.arch
+    ops = sum(ref.ops_per_token(a) * c + ref.span_attention_ops(a, p0, c)
+              for s in run.steps for p0, c, _ in s.spans)
+    return ops + ref.head_ops(a) + ref.decode_attn_work(a, [8])[0]
+""",
+    "norm_s": """def read(run):
+    return (run.trace or {}).get("kernels", {}).get("norm", {}).get("s")
+"""}
+
+
+def test_new_family_and_kernel_found_by_name(files, monkeypatch):
+    """A family (a copy of ``dense`` whose work counts record their calls)
+    named by a new configuration, and a cost file for a kernel the
+    recorded v5e trace holds (``int_layernorm``), added under the test's
+    files directory with an entry each in the benchmark's list: run_cell
+    loads the family's reference and program by name and hands the family
+    to the readers, and the trace reduction finds the kernel (the CPU's
+    trace has no TPU plane; the recorded one stands in for it)."""
+    fam_dir = files / "families" / "dense_counted"
+    shutil.copytree(files / "families" / "dense", fam_dir)
+    with open(fam_dir / "reference.py", "a") as f:
+        f.write(RECORDER)
+    conf = json.loads((files / "configs" / f"{CONFIG}.json").read_text())
+    conf["family"] = "dense_counted"
+    (files / "configs" / "tiny-counted.json").write_text(json.dumps(conf))
+    (files / "kernels" / "int_layernorm.py").write_text(KERNEL)
+    for name, text in READERS.items():
+        (files / "metrics" / f"{name}.py").write_text(text)
+    with gzip.open(os.path.join(TESTS, "data", "trace_v5e.json.gz"),
+                   "rt") as f:
+        chip = json.load(f)
+    monkeypatch.setattr(harness.devtrace, "events", lambda *a: chip)
+
+    bench = tiny_bench("tiny-counted", "tiny-offline", list(READERS))
+    out = harness.run_cell(str(files), "cell", SEED, 0.5, True,
+                           time.perf_counter(), bench=bench,
+                           files=str(files))
+    assert out["correct"], out["check"]
+    fam = model.family("dense_counted", str(files))
+    assert fam.program.reference is fam.reference
+    assert set(fam.reference.CALLS) == {"ops_per_token", "head_ops",
+                                        "span_attention_ops",
+                                        "decode_attn_work"}
+    assert out["metrics"]["model_ops"]["value"] > 0
+    norm_s = out["metrics"]["norm_s"]["value"]
+    assert norm_s > 0
+
+    # with the chip's peaks, the reduction costs the new kernel from the run
+    pk = roofline.peaks("TPU v5 lite")
+    run = harness.Run("c", 4, fam.reference.arch(conf["model"]), fam, 1.0,
+                      0.0, 1.0, [], [], pk, (0.0, 1.0))
+    red = harness.devtrace.reduce(chip, run,
+                                  harness.devtrace.kernels(str(files)))
+    assert red["kernels"]["norm"] == {
+        "s": norm_s, "min_s": roofline.min_seconds(1e9, 2e9, pk,
+                                                   "bf16_flops")}
+    assert "gemm" in red["kernels"] and "decode_attn" in red["kernels"]
 
 
 def test_run_refuses_without_an_accelerator(tmp_path):

@@ -2,10 +2,11 @@
 counted inside the window, decode steps timed by the host, and a stall
 that moves both; the comparison's sample."""
 import harness
-import roofline
+import model
 
 ARCH = {"d": 64, "layers": 2, "heads": 4, "kv_heads": 2, "head_dim": 16,
         "ff": 128, "vocab": 100, "gated": False}
+DENSE = model.family("dense")
 
 
 def read(name, run):
@@ -22,7 +23,7 @@ def make_run(stall_s=0.0):
         ts = [t0 + 0.05 + 0.02 * k for k in range(10)]
         ts = [t + stall_s if t >= 1.2 else t for t in ts]
         reqs.append(harness.Request(i, [5] * 8, 10, ts, [7] * 10))
-    return harness.Run("c", 4, ARCH, 12.5, 1.0, 1.5, reqs, [], {})
+    return harness.Run("c", 4, ARCH, DENSE, 12.5, 1.0, 1.5, reqs, [], {})
 
 
 def test_tokens_counted_inside_the_window():
@@ -59,12 +60,31 @@ def test_step_mfu_counts_every_token_once():
     run = make_run()
     run.steps = _steps()
     run.peaks = {"int8_ops": 1e12}
-    per = roofline.layer_ops_per_token(ARCH)
-    att = roofline.attention_ops(1, ARCH)
+    per = 2.0 * 2 * (64 * 64 + 2 * 64 * 32 + 64 * 64 + 2 * 64 * 128)
+    att = 4.0 * 16 * 4 * 2
     emitted = sum(1.0 <= t < 1.5 for r in run.requests for t in r.token_t)
     ops = (per * 11 + att * (11 + 21 + sum(range(1, 9)) + 22)
-           + roofline.head_ops(ARCH) * emitted)
+           + 2.0 * 64 * 100 * emitted)
     assert abs(read("step_mfu", run) - 100 * ops / (0.5 * 1e12)) < 1e-9
+    # what the reader gave when it made the counts itself, to the last bit
+    assert read("step_mfu", run) == 0.0003588096
+
+
+def test_step_mfu_at_full_width_is_the_parents():
+    """starcoder2-3b's widths over forty 8-lane decode steps, two prompt
+    spans and the synthetic steps: the count the reader made before the
+    dense family made it, to the last bit."""
+    run = make_run()
+    run.arch = dict(ARCH, d=3072, layers=30, heads=24, head_dim=128,
+                    ff=12288, vocab=49152)
+    run.peaks = {"int8_ops": 1e12}
+    S = harness.Step
+    run.steps = [S(0.1 + 0.01 * i, 0.105 + 0.01 * i, 0, 8,
+                   [(500 + 131 * lane + 7 * i, 1, lane == 3)
+                    for lane in range(8)]) for i in range(40)]
+    run.steps += _steps() + [S(1.0, 1.2, 256, 0,
+                               [(0, 256, True), (700, 64, True)])]
+    assert read("step_mfu", run) == 783.5613954048
 
 
 def test_readers_find_nothing_without_a_trace():
@@ -106,7 +126,7 @@ def test_spread_takes_first_and_last():
 def test_too_few_requests_is_not_correct():
     conf = {"correct": {"mean_logit_gap": 1.0, "sample_requests": 32,
                         "min_requests": 8, "tokens_per_request": 4}}
-    check = harness.compare(conf, 3, [])
+    check = harness.compare(conf, DENSE, 3, [])
     assert check == {"requests_checked": {"value": 0, "limit": 8,
                                           "at_least": True}}
     assert not all(harness.holds(c) for c in check.values())

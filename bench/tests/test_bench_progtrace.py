@@ -93,7 +93,8 @@ def test_recorded_v5e_steps():
 
 
 def test_readers_without_a_trace_read_none():
-    run = harness.Run("c", 4, {}, 1.0, 0.0, 1.0, [], [], {})
+    run = harness.Run("c", 4, {}, model.family("dense"), 1.0, 0.0, 1.0,
+                      [], [], {})
     for name in NEW:
         assert harness.reader(name)(run) is None
 
@@ -140,9 +141,11 @@ def test_plan_record_matches_the_harness_plans(files, tmp_path):
     """The ``engine.plan`` span's record is the one the benchmark's
     wrapper of ``_plan_tokens`` makes, step for step."""
     conf = model.load(CONFIG, str(files))
-    cfg = model.arch_config(conf)
+    fam = model.family(conf["family"], str(files))
+    cfg = fam.program.arch_config(conf)
     eng = model.build_engine(conf, cfg,
-                             model.program_params(conf, cfg, SEED), SEED)
+                             fam.program.program_params(conf, cfg, SEED),
+                             SEED)
     plans = harness._Plans(eng)
     seen = []
     with _profile(tmp_path):
